@@ -90,6 +90,8 @@ def run(quick: bool = False, arch: str = "yi-6b", shape: str = "train_4k"):
            "--arch", arch, "--shape", shape] + (["--quick"] if quick else [])
     env = dict(os.environ)
     env["PYTHONPATH"] = env.get("PYTHONPATH", "src")
+    # the compiled fidelity lowers onto the forced host devices: CPU only
+    env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.run(cmd, env=env, cwd=os.path.dirname(
         os.path.dirname(os.path.abspath(__file__))))
     if proc.returncode != 0:

@@ -117,6 +117,9 @@ def _worker(args) -> dict:
 def _spawn_worker(nd: int, n: int, d: int, q: int, pool: int,
                   repeats: int) -> dict:
     env = dict(os.environ)
+    # the worker measures forced host devices by design: CPU only, so it
+    # never competes with this process for an attached accelerator
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (f"--xla_force_host_platform_device_count={nd} "
                         "--xla_cpu_multi_thread_eigen=false")
     env["PYTHONPATH"] = os.pathsep.join(

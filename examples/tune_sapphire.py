@@ -13,6 +13,7 @@ recommended config diff.
 import argparse
 import json
 
+from repro.compile_cache import use_compile_cache
 from repro.core.strategy import BOConfig, strategy_names
 from repro.core.tuner import Sapphire
 
@@ -36,6 +37,7 @@ def main():
                          "services that stream completions out of order)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    use_compile_cache()
 
     s = Sapphire(
         arch=args.arch, shape=args.shape, top_k=args.top_k,

@@ -28,6 +28,13 @@ import numpy as np
 SQRT5 = math.sqrt(5.0)
 
 
+def _mm(a, b):
+    """f32 matmul at full precision.  The TPU's default is one bf16 pass,
+    which the Gram's a² + b² − 2ab cancellation and the posterior solves
+    cannot absorb; the CPU computes f32 either way."""
+    return jnp.matmul(a, b, precision=jax.lax.Precision.HIGHEST)
+
+
 # ---------------------------------------------------------------------------
 # kernels
 # ---------------------------------------------------------------------------
@@ -38,7 +45,7 @@ def _sqdist(xa, xb, inv_ls):
     b = xb * inv_ls
     a2 = jnp.sum(a * a, axis=1)[:, None]
     b2 = jnp.sum(b * b, axis=1)[None, :]
-    return jnp.maximum(a2 + b2 - 2.0 * (a @ b.T), 0.0)
+    return jnp.maximum(a2 + b2 - 2.0 * _mm(a, b.T), 0.0)
 
 
 def matern52(xa, xb, lengthscale, signal_var):
@@ -183,7 +190,7 @@ _build_jit = partial(jax.jit, static_argnames=("kind", "use_pallas"))(_build)
 def neg_log_marginal(params: GPParams, x, y, kind: str, extra_noise=None):
     chol, alpha = _build(params, x, y, kind, extra_noise)
     n = x.shape[0]
-    return (0.5 * y @ alpha
+    return (0.5 * _mm(y, alpha)
             + jnp.sum(jnp.log(jnp.diagonal(chol)))
             + 0.5 * n * math.log(2 * math.pi))
 
@@ -428,7 +435,7 @@ def mt_neg_log_marginal(params: MTGPParams, x, tasks, y, kind: str,
                         extra_noise=None):
     chol, alpha, r = _mt_build(params, x, tasks, y, kind, extra_noise)
     n = x.shape[0]
-    return (0.5 * r @ alpha
+    return (0.5 * _mm(r, alpha)
             + jnp.sum(jnp.log(jnp.diagonal(chol)))
             + 0.5 * n * math.log(2 * math.pi))
 
@@ -521,7 +528,7 @@ def _mt_predict(state: MTGPState, xq, w_q, kappa_q, off_q, kind: str):
     sv = jnp.exp(state.params.log_signal_var)
     kq = (KERNELS[kind](xq, state.x, ls, sv)
           * (w_q * state.params.task_w)[state.tasks][None, :])
-    mean_s = off_q + kq @ state.alpha
+    mean_s = off_q + _mm(kq, state.alpha)
     v = jax.scipy.linalg.solve_triangular(state.chol, kq.T, lower=True)
     prior = (w_q * w_q + kappa_q) * sv
     var_s = jnp.maximum(prior - jnp.sum(v * v, axis=0), 1e-12)
@@ -565,7 +572,7 @@ def predict(state: GPState, xq, kind: str = "matern52",
         kq = matern52_cross(xq, state.x, ls, sv)     # [m, n]
     else:
         kq = KERNELS[kind](xq, state.x, ls, sv)      # [m, n]
-    mean_s = kq @ state.alpha
+    mean_s = _mm(kq, state.alpha)
     v = jax.scipy.linalg.solve_triangular(state.chol, kq.T, lower=True)
     var_s = jnp.maximum(sv - jnp.sum(v * v, axis=0), 1e-12)
     mean = mean_s * state.y_std + state.y_mean
@@ -606,7 +613,7 @@ def chol_append(chol, k_vec, k_ss):
     fantasy update of q-batch acquisition without rebuilding anything.
     """
     l = jax.scipy.linalg.solve_triangular(chol, k_vec, lower=True)
-    d = jnp.sqrt(jnp.maximum(k_ss - jnp.dot(l, l), 1e-12))
+    d = jnp.sqrt(jnp.maximum(k_ss - _mm(l, l), 1e-12))
     return l, d
 
 
@@ -722,7 +729,7 @@ def select_batch(state: GPState, cand, y_raw, n, best_y, q: int,
         std_y = jnp.sqrt(jnp.sum(w * (yr - mu_y) ** 2) / cnt)
         std_y = jnp.where(std_y < 1e-12, 1.0, std_y)
 
-        mean_s = (v.T @ (a - mu_y * b)) / std_y
+        mean_s = _mm(v.T, a - mu_y * b) / std_y
         var_s = jnp.maximum(sv - jnp.sum(v * v, axis=0), 1e-12)
         mean = mean_s * std_y + mu_y
         std = jnp.sqrt(var_s) * std_y
@@ -754,9 +761,9 @@ def select_batch(state: GPState, cand, y_raw, n, best_y, q: int,
                              chol)
             # grow the forward-substitution state by the appended row
             col_c = kfn(cand, x_new[None], ls, sv)[:, 0]
-            v = jnp.where(grow, v.at[row, :].set((col_c - l @ v) / dg), v)
-            a = jnp.where(grow, a.at[row].set((lie - l @ a) / dg), a)
-            b = jnp.where(grow, b.at[row].set((1.0 - l @ b) / dg), b)
+            v = jnp.where(grow, v.at[row, :].set((col_c - _mm(l, v)) / dg), v)
+            a = jnp.where(grow, a.at[row].set((lie - _mm(l, a)) / dg), a)
+            b = jnp.where(grow, b.at[row].set((1.0 - _mm(l, b)) / dg), b)
             y_f = jnp.where(grow, y_f.at[slot].set(lie), y_f)
             x_f = jnp.where(grow, x_f.at[slot, :].set(x_new), x_f)
         return (chol, v, a, b, y_f, x_f, taken), i
@@ -778,7 +785,7 @@ def _select_scan_sharded(state: GPState, cand_l, taken0_l, y_raw, n, best_y,
                          axis: str = "pool"):
     """Shard-local body of :func:`select_batch_sharded`.
 
-    Runs under ``shard_map``/``pmap`` with ``cand_l`` [Ml, d] the local
+    Runs under ``jax.shard_map`` with ``cand_l`` [Ml, d] the local
     shard of the pool and everything else replicated.  Mirrors
     :func:`select_batch` step for step; the only cross-device traffic per
     pick is the argmax reduction (one pmax + one pmin) and three masked
@@ -849,7 +856,7 @@ def _select_scan_sharded(state: GPState, cand_l, taken0_l, y_raw, n, best_y,
         std_y = jnp.sqrt(jnp.sum(w * (yr - mu_y) ** 2) / cnt)
         std_y = jnp.where(std_y < 1e-12, 1.0, std_y)
 
-        mean_s = (v.T @ (a - mu_y * b)) / std_y             # [Ml]
+        mean_s = _mm(v.T, a - mu_y * b) / std_y             # [Ml]
         var_s = jnp.maximum(sv - jnp.sum(v * v, axis=0), 1e-12)
         mean = mean_s * std_y + mu_y
         std = jnp.sqrt(var_s) * std_y
@@ -896,9 +903,9 @@ def _select_scan_sharded(state: GPState, cand_l, taken0_l, y_raw, n, best_y,
             chol = jnp.where(grow, chol.at[row, :].set(l.at[row].set(dg)),
                              chol)
             col_c = kfn(cand_l, x_new[None], ls, sv)[:, 0]
-            v = jnp.where(grow, v.at[row, :].set((col_c - l @ v) / dg), v)
-            a = jnp.where(grow, a.at[row].set((lie - l @ a) / dg), a)
-            b = jnp.where(grow, b.at[row].set((1.0 - l @ b) / dg), b)
+            v = jnp.where(grow, v.at[row, :].set((col_c - _mm(l, v)) / dg), v)
+            a = jnp.where(grow, a.at[row].set((lie - _mm(l, a)) / dg), a)
+            b = jnp.where(grow, b.at[row].set((1.0 - _mm(l, b)) / dg), b)
             y_f = jnp.where(grow, y_f.at[slot].set(lie), y_f)
             x_f = jnp.where(grow, x_f.at[slot, :].set(x_new), x_f)
         return (chol, v, a, b, y_f, x_f, taken), gi
@@ -908,30 +915,23 @@ def _select_scan_sharded(state: GPState, cand_l, taken0_l, y_raw, n, best_y,
 
 
 # compiled sharded selectors, keyed by (devices, q, kind, fantasy,
-# acquisition, use_pallas, use_shard_map) — shapes retrace under jit/pmap
+# acquisition, use_pallas) — shapes retrace under jit
 _SHARDED_CACHE: dict = {}
 
 
-def _sharded_fn(devs, q, kind, fantasy, acquisition, use_pallas,
-                use_shard_map):
-    key = (devs, q, kind, fantasy, acquisition, use_pallas, use_shard_map)
+def _sharded_fn(devs, q, kind, fantasy, acquisition, use_pallas):
+    key = (devs, q, kind, fantasy, acquisition, use_pallas)
     fn = _SHARDED_CACHE.get(key)
     if fn is not None:
         return fn
+    from jax.sharding import Mesh, PartitionSpec as P
     body = partial(_select_scan_sharded, q=q, kind=kind, fantasy=fantasy,
                    acquisition=acquisition, use_pallas=use_pallas)
-    if use_shard_map:
-        from jax.experimental.shard_map import shard_map
-        from jax.sharding import Mesh, PartitionSpec as P
-        mesh = Mesh(np.array(devs), ("pool",))
-        fn = jax.jit(shard_map(
-            body, mesh=mesh,
-            in_specs=(P(), P("pool"), P("pool"), P(), P(), P(), P()),
-            out_specs=P(), check_rep=False))
-    else:
-        fn = jax.pmap(body, axis_name="pool",
-                      in_axes=(None, 0, 0, None, None, None, None),
-                      devices=list(devs))
+    mesh = Mesh(np.array(devs), ("pool",))
+    fn = jax.jit(jax.shard_map(
+        body, mesh=mesh,
+        in_specs=(P(), P("pool"), P("pool"), P(), P(), P(), P()),
+        out_specs=P(), check_vma=False))
     _SHARDED_CACHE[key] = fn
     return fn
 
@@ -939,30 +939,23 @@ def _sharded_fn(devs, q, kind, fantasy, acquisition, use_pallas,
 def select_batch_sharded(state: GPState, cand, y_raw, n, best_y, q: int,
                          kind: str = "matern52", fantasy: str = "liar",
                          acquisition: str = "ei", xi: float = 0.01,
-                         use_pallas: bool = False, devices=None,
-                         use_shard_map: Optional[bool] = None):
+                         use_pallas: bool = False, devices=None):
     """:func:`select_batch` with the candidate pool sharded over devices.
 
     The pool (LHS + local ball + axis sweeps, [M, d]) is split row-wise
-    across ``devices`` (default: all host devices); each device scores
-    its shard against the replicated posterior and a masked all-reduce
-    argmax picks every winner.  Per-pick traffic is O(m + d) — constant
-    in pool size — so M can grow with ``jax.device_count()`` at constant
-    wall-clock.
+    across ``devices`` (default: all host devices) by ``jax.shard_map``;
+    each device scores its shard against the replicated posterior and a
+    masked all-reduce argmax picks every winner.  Per-pick traffic is
+    O(m + d) — constant in pool size — so M can grow with
+    ``jax.device_count()`` at constant wall-clock.
 
     Picks are bit-identical to :func:`select_batch` on the same pool (see
     :func:`_select_scan_sharded` for the tie-break argument).  The pool
     is padded to a multiple of the device count with unit-cube midpoints
     pre-marked taken, so padding never changes a pick.
-
-    ``use_shard_map`` selects the mesh entry point: ``shard_map`` (the
-    mesh-native path, default off-CPU) or ``pmap`` (the CPU-host
-    fallback, where forced host devices lack a true mesh runtime).
     """
     devs = tuple(devices) if devices is not None else tuple(jax.devices())
     nd = len(devs)
-    if use_shard_map is None:
-        use_shard_map = devs[0].platform != "cpu"
     cand = jnp.asarray(cand, jnp.float32)
     M, d = cand.shape
     Ml = -(-M // nd)
@@ -971,14 +964,7 @@ def select_batch_sharded(state: GPState, cand, y_raw, n, best_y, q: int,
         cand = jnp.concatenate(
             [cand, jnp.full((Mp - M, d), 0.5, jnp.float32)])
     taken0 = jnp.arange(Mp) >= M
-    fn = _sharded_fn(devs, q, kind, fantasy, acquisition, bool(use_pallas),
-                     bool(use_shard_map))
-    y_raw = jnp.asarray(y_raw, jnp.float32)
-    n = jnp.asarray(n, jnp.int32)
-    best_y = jnp.asarray(best_y, jnp.float32)
-    xi = jnp.asarray(xi, jnp.float32)
-    if use_shard_map:
-        return fn(state, cand, taken0, y_raw, n, best_y, xi)
-    picks = fn(state, cand.reshape(nd, Ml, d), taken0.reshape(nd, Ml),
-               y_raw, n, best_y, xi)
-    return picks[0]
+    fn = _sharded_fn(devs, q, kind, fantasy, acquisition, bool(use_pallas))
+    return fn(state, cand, taken0, jnp.asarray(y_raw, jnp.float32),
+              jnp.asarray(n, jnp.int32), jnp.asarray(best_y, jnp.float32),
+              jnp.asarray(xi, jnp.float32))

@@ -24,6 +24,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+# f32 matmuls at full precision: the TPU's default is one bf16 pass, which
+# perturbs the Gram and the gradient steps of the path well beyond the
+# solver's tolerance (the CPU computes f32 either way)
+_mm = partial(jnp.matmul, precision=jax.lax.Precision.HIGHEST)
+
 
 class Standardized(NamedTuple):
     x: jnp.ndarray       # [n, d] standardized features
@@ -46,7 +51,7 @@ def standardize(x: jnp.ndarray, y: jnp.ndarray) -> Standardized:
 def lambda_max(std: Standardized) -> float:
     """Smallest λ with all-zero solution: max |xᵀy| / n."""
     n = std.x.shape[0]
-    return float(jnp.max(jnp.abs(std.x.T @ std.y)) / n)
+    return float(jnp.max(jnp.abs(_mm(std.x.T, std.y))) / n)
 
 
 def _cd_impl(x, y, lam, beta0, max_iter: int = 500, tol: float = 1e-6):
@@ -60,14 +65,14 @@ def _cd_impl(x, y, lam, beta0, max_iter: int = 500, tol: float = 1e-6):
             beta, r = state                        # r = y - x @ beta
             bj = beta[j]
             xj = x[:, j]
-            rho = (xj @ r) / n + col_sq[j] * bj
+            rho = _mm(xj, r) / n + col_sq[j] * bj
             bj_new = jnp.sign(rho) * jnp.maximum(jnp.abs(rho) - lam, 0.0) \
                 / jnp.maximum(col_sq[j], 1e-12)
             r = r + xj * (bj - bj_new)
             beta = beta.at[j].set(bj_new)
             return beta, r
 
-        r = y - x @ beta
+        r = y - _mm(x, beta)
         beta_new, _ = jax.lax.fori_loop(0, d, body, (beta, r))
         return beta_new
 
@@ -101,8 +106,8 @@ def _fista_path(x, y, lams, max_iter: int, tol: float = 1e-7):
     the grid (the standard pathwise warm start) in one dispatch.
     """
     n, d = x.shape
-    g = x.T @ x / n                                 # [d, d] gram
-    b = x.T @ y / n                                 # [d]
+    g = _mm(x.T, x) / n                             # [d, d] gram
+    b = _mm(x.T, y) / n                             # [d]
     # Lipschitz constant of ∇(½‖y−xβ‖²/n): the exact top eigenvalue (an
     # underestimate would make the gradient step overshoot and the whole
     # warm-started path diverge silently). One [d,d] eigh per path call
@@ -120,7 +125,7 @@ def _fista_path(x, y, lams, max_iter: int, tol: float = 1e-7):
 
         def step(state):
             beta, z, t, _, it = state
-            beta_new = soft(z - (g @ z - b) / lip, lam / lip)
+            beta_new = soft(z - (_mm(g, z) - b) / lip, lam / lip)
             t_new = 0.5 * (1.0 + jnp.sqrt(1.0 + 4.0 * t * t))
             z = beta_new + (t - 1.0) / t_new * (beta_new - beta)
             return beta_new, z, t_new, beta, it + 1
@@ -163,8 +168,8 @@ def ridge_fit(x, y, lam: float) -> np.ndarray:
     """Closed-form ridge (comparison baseline; cannot select features)."""
     std = standardize(x, y)
     n, d = std.x.shape
-    a = std.x.T @ std.x / n + lam * jnp.eye(d, dtype=jnp.float32)
-    b = std.x.T @ std.y / n
+    a = _mm(std.x.T, std.x) / n + lam * jnp.eye(d, dtype=jnp.float32)
+    b = _mm(std.x.T, std.y) / n
     return np.asarray(jnp.linalg.solve(a, b))
 
 

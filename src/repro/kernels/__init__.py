@@ -15,17 +15,26 @@ from typing import Optional
 
 def tuning_compiler_params(num_warps: Optional[int] = None,
                            pipeline: Optional[int] = None,
-                           interpret: bool = False):
+                           interpret: bool = False,
+                           vmem_limit_bytes: Optional[int] = None):
     """``pallas_call`` compiler params for the tunable scheduling knobs.
 
     ``num_warps``/``pipeline`` (pipeline depth → Triton ``num_stages``)
     only exist on the GPU lowering; on TPU the Mosaic pipeline is derived
     from the BlockSpecs and in interpret mode there is no compiler at
-    all — those paths get ``None`` (pass nothing), so the knobs are
-    *inert* off-GPU and the autotune space stays portable."""
+    all — those knobs are *inert* off-GPU and the autotune space stays
+    portable.  ``vmem_limit_bytes`` raises Mosaic's scoped VMEM limit for
+    a kernel whose tiles need more than the default; it applies to every
+    non-interpreted compile off the GPU, which is a compile for the TPU
+    (the chip need not be attached)."""
     import jax
-    if interpret or jax.default_backend() != "gpu":
+    if interpret:
         return None
+    if jax.default_backend() != "gpu":
+        if not vmem_limit_bytes:
+            return None
+        from jax.experimental.pallas import tpu as pltpu
+        return pltpu.CompilerParams(vmem_limit_bytes=int(vmem_limit_bytes))
     params = {}
     if num_warps:
         params["num_warps"] = int(num_warps)

@@ -3,6 +3,9 @@
 ``flash_attention(q, k, v)`` takes the model-zoo layout [B, S, H, D] /
 [B, S, Kh, D], pads sequence lengths up to the block grid, flattens heads,
 runs the Pallas kernel (interpret mode off-TPU) and restores the layout.
+The kernel computes the forward only; its VJP is that of the chunked
+online-softmax form it matches (``ref.chunked_attention``), so the train
+step can differentiate through the kernel.
 """
 
 from __future__ import annotations
@@ -14,6 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.flash_attention.kernel import flash_attention_fwd
+from repro.kernels.flash_attention.ref import chunked_attention
 
 
 def _pad_to(x, target: int, axis: int):
@@ -40,10 +44,17 @@ def flash_attention(q, k, v, *, causal: bool = True,
 
     ``block_q``/``block_k``/``num_warps``/``pipeline`` are SAPPHIRE
     autotune knobs (:func:`autotune_space`); the output is
-    tiling-invariant (tests/test_kernels.py guards this).
+    tiling-invariant (tests/test_kernels.py guards this).  Differentiable
+    (see the module docstring).
     """
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
+    return _flash(q, k, v, causal, window, softcap, block_q, block_k,
+                  num_warps, pipeline, interpret)
+
+
+def _forward(q, k, v, causal, window, softcap, block_q, block_k, num_warps,
+             pipeline, interpret):
     B, Sq, H, D = q.shape
     _, Sk, Kh, _ = k.shape
 
@@ -62,6 +73,57 @@ def flash_attention(q, k, v, *, causal: bool = True,
                             sq_valid=Sq, sk_valid=Sk, interpret=interpret)
     o = o.reshape(B, H, sq_pad, D).transpose(0, 2, 1, 3)
     return o[:, :Sq]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=tuple(range(3, 11)))
+def _flash(q, k, v, causal, window, softcap, block_q, block_k, num_warps,
+           pipeline, interpret):
+    return _forward(q, k, v, causal, window, softcap, block_q, block_k,
+                    num_warps, pipeline, interpret)
+
+
+def _flash_fwd(q, k, v, causal, window, softcap, block_q, block_k,
+               num_warps, pipeline, interpret):
+    out = _forward(q, k, v, causal, window, softcap, block_q, block_k,
+                   num_warps, pipeline, interpret)
+    return out, (q, k, v)
+
+
+def _flash_bwd(causal, window, softcap, block_q, block_k, num_warps,
+               pipeline, interpret, res, g):
+    """VJP of the chunked form, one query block at a time: attention rows
+    are independent, so each block's VJP (keys offset by the block start)
+    is exact, and the saved softmax tiles are [block_q, Sk] instead of
+    [Sq, Sk].  f32 operands keep the per-block dk/dv unrounded."""
+    q, k, v = res
+    B, Sq, H, D = q.shape
+    bq = min(block_q, Sq)
+    n_qb = -(-Sq // bq)
+    f32 = jnp.float32
+
+    def blocks(x):
+        x = _pad_to(x.astype(f32), n_qb * bq, 1)
+        return x.reshape(B, n_qb, bq, H, D).transpose(1, 0, 2, 3, 4)
+
+    def body(carry, xs):
+        dk, dv = carry
+        i, qb, gb = xs
+        _, vjp = jax.vjp(
+            lambda qb, kf, vf: chunked_attention(
+                qb, kf, vf, causal=causal, window=window, softcap=softcap,
+                chunk=block_k, offset=i * bq),
+            qb, k.astype(f32), v.astype(f32))
+        dq_b, dk_b, dv_b = vjp(gb)
+        return (dk + dk_b, dv + dv_b), dq_b
+
+    zeros = (jnp.zeros(k.shape, f32), jnp.zeros(v.shape, f32))
+    (dk, dv), dq = jax.lax.scan(body, zeros,
+                                (jnp.arange(n_qb), blocks(q), blocks(g)))
+    dq = dq.transpose(1, 0, 2, 3, 4).reshape(B, n_qb * bq, H, D)[:, :Sq]
+    return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
+
+
+_flash.defvjp(_flash_fwd, _flash_bwd)
 
 
 def _round_up(x: int, m: int) -> int:

@@ -32,7 +32,10 @@ def _kernel(xa_ref, xb_ref, o_ref, *, signal_var: float):
     b = xb_ref[...]                          # [bm, d]
     a2 = jnp.sum(a * a, axis=1, keepdims=True)           # [bn, 1]
     b2 = jnp.sum(b * b, axis=1, keepdims=True).T         # [1, bm]
+    # full f32 precision: a single bf16 pass (the TPU default) loses the
+    # small distances to the a² + b² − 2ab cancellation
     ab = jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                             precision=jax.lax.Precision.HIGHEST,
                              preferred_element_type=jnp.float32)
     d2 = jnp.maximum(a2 + b2 - 2.0 * ab, 0.0)
     safe = jnp.where(d2 > 1e-12, d2, 1.0)

@@ -87,7 +87,10 @@ def autotune_space():
         knobs=(
             pow2_knob("block_n", 128, 8, 512,
                       description="output row tile"),
-            pow2_knob("block_m", 128, 8, 512,
+            # the column tile is the output block's lane dimension: the
+            # TPU lowering takes a multiple of 128 (or the whole padded
+            # width, which the wrapper clamps wider tiles to)
+            pow2_knob("block_m", 128, 128, 512,
                       description="output column tile"),
             pow2_knob("num_warps", 4, 1, 8, inert=True,
                       description="GPU warps per block (inert off-GPU)"),

@@ -1,4 +1,9 @@
-"""jit'd wrapper for the chunkwise mLSTM kernel (model layout in/out)."""
+"""jit'd wrapper for the chunkwise mLSTM kernel (model layout in/out).
+
+The kernel computes the forward only; its VJP is that of the jnp chunked
+form it matches (``ref.mlstm_chunked``), so the train step can
+differentiate through the kernel.
+"""
 
 from __future__ import annotations
 
@@ -8,6 +13,41 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.mlstm_chunk.kernel import mlstm_chunk_fwd
+from repro.kernels.mlstm_chunk.ref import mlstm_chunked
+
+
+def _forward(q, k, v, logi, logf, chunk, num_warps, pipeline, interpret):
+    B, S, H, P = q.shape
+    to_flat = lambda t: t.transpose(0, 2, 1, 3).reshape(B * H, S, P)  # noqa
+    gate_flat = lambda t: t.transpose(0, 2, 1).reshape(B * H, S, 1)   # noqa
+    h = mlstm_chunk_fwd(to_flat(q), to_flat(k), to_flat(v),
+                        gate_flat(logi).astype(jnp.float32),
+                        gate_flat(logf).astype(jnp.float32),
+                        chunk=chunk, num_warps=num_warps,
+                        pipeline=pipeline, interpret=interpret)
+    return h.reshape(B, H, S, P).transpose(0, 2, 1, 3)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+def _mlstm(q, k, v, logi, logf, chunk, num_warps, pipeline, interpret):
+    return _forward(q, k, v, logi, logf, chunk, num_warps, pipeline,
+                    interpret)
+
+
+def _mlstm_fwd(q, k, v, logi, logf, chunk, num_warps, pipeline, interpret):
+    out = _forward(q, k, v, logi, logf, chunk, num_warps, pipeline,
+                   interpret)
+    return out, (q, k, v, logi, logf)
+
+
+def _mlstm_bwd(chunk, num_warps, pipeline, interpret, res, g):
+    dtype = res[0].dtype
+    _, vjp = jax.vjp(
+        lambda *a: mlstm_chunked(*a, chunk=chunk).astype(dtype), *res)
+    return vjp(g)
+
+
+_mlstm.defvjp(_mlstm_fwd, _mlstm_bwd)
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "num_warps",
@@ -19,19 +59,12 @@ def mlstm_chunk(q, k, v, logi, logf, *, chunk: int = 256,
 
     k must already carry the 1/sqrt(P) scale (as models/xlstm.py projects).
     ``chunk``/``num_warps``/``pipeline`` are SAPPHIRE autotune knobs
-    (:func:`autotune_space`).
+    (:func:`autotune_space`).  Differentiable (see the module docstring).
     """
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    B, S, H, P = q.shape
-    to_flat = lambda t: t.transpose(0, 2, 1, 3).reshape(B * H, S, P)  # noqa
-    gate_flat = lambda t: t.transpose(0, 2, 1).reshape(B * H, S)      # noqa
-    h = mlstm_chunk_fwd(to_flat(q), to_flat(k), to_flat(v),
-                        gate_flat(logi).astype(jnp.float32),
-                        gate_flat(logf).astype(jnp.float32),
-                        chunk=min(chunk, S), num_warps=num_warps,
-                        pipeline=pipeline, interpret=interpret)
-    return h.reshape(B, H, S, P).transpose(0, 2, 1, 3)
+    return _mlstm(q, k, v, logi, logf, min(chunk, q.shape[1]), num_warps,
+                  pipeline, interpret)
 
 
 # ---------------------------------------------------------------------------

@@ -11,13 +11,19 @@ real single CPU device.
 
 from __future__ import annotations
 
+import math
+
 import jax
 
 
 def make_production_mesh(*, multi_pod: bool = False):
+    """The deployment mesh over the dry-run's forced host (CPU) devices —
+    built from ``jax.devices("cpu")`` so that it starts on a machine
+    whose default backend is an attached accelerator, too."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         devices=jax.devices("cpu")[:math.prod(shape)])
 
 
 def make_host_mesh():

@@ -11,6 +11,7 @@ import time
 import jax
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.configs import get_config, get_smoke_config
 from repro.models.model import Model
 from repro.runconfig import runconfig_from_knobs
@@ -29,6 +30,7 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--knob", action="append", default=[])
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     cfg = get_config(args.arch) if args.full else get_smoke_config(args.arch)
     rc = runconfig_from_knobs(parse_knobs(args.knob))

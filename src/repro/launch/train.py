@@ -17,6 +17,7 @@ import time
 
 import jax
 
+from repro.compile_cache import use_compile_cache
 from repro.configs import get_config, get_smoke_config
 from repro.launch.mesh import make_host_mesh
 from repro.models.model import Model
@@ -61,6 +62,7 @@ def main(argv=None):
     ap.add_argument("--knob", action="append", default=[],
                     help="RunConfig override, e.g. --knob microbatch=2")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     rc = runconfig_from_knobs(parse_knobs(args.knob))
@@ -76,8 +78,10 @@ def main(argv=None):
         if cm and args.resume and cm.latest_step() is not None:
             state, start = cm.restore(state)
             print(f"resumed from step {start}")
+        # the state is donated: the step's output replaces it in place
+        # instead of holding params and optimizer state twice
         step_fn = jax.jit(make_train_step(
-            model, rc, lr_schedule=lambda s: args.lr))
+            model, rc, lr_schedule=lambda s: args.lr), donate_argnums=(0,))
         data = SyntheticDataset(args.seed, args.global_batch, args.seq_len,
                                 cfg.vocab_size, start_step=start)
         t_last = time.monotonic()

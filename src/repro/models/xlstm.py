@@ -114,79 +114,21 @@ def mlstm_apply(params, u, cfg: ModelConfig, rc: RunConfig):
     """Chunkwise-parallel stabilized mLSTM.  u [B,S,d] -> [B,S,d].
 
     On TPU the chunk recurrence dispatches to the Pallas kernel
-    (kernels/mlstm_chunk); elsewhere the jnp scan below is the compiled
+    (kernels/mlstm_chunk, differentiable through the jnp form's VJP);
+    elsewhere the jnp chunked form (``ref.mlstm_chunked``) is the compiled
     path and the kernel's oracle.
     """
     B, S, _ = u.shape
     di, nh, P = mlstm_dims(cfg)
     q, k, v, logi, logf, z = _mlstm_qkvg(params, u, cfg)
 
-    if jax.default_backend() == "tpu" and S % rc.mlstm_chunk == 0:
-        from repro.kernels.mlstm_chunk.ops import mlstm_chunk as _kernel
-        h = _kernel(q, k, v, logi, logf, chunk=rc.mlstm_chunk)
-        h = h.reshape(B, S, di)
-        h = norm_apply(params["out_norm"],
-                       h.astype(u.dtype)
-                       * jax.nn.silu(z.astype(jnp.float32)).astype(u.dtype),
-                       kind="rmsnorm", eps=cfg.norm_eps)
-        return dense_apply(params["down"], h)
-
-    c = min(rc.mlstm_chunk, S)
-    assert S % c == 0, "mlstm_chunk must divide seq len"
-    n_chunks = S // c
-
-    def csh(t, tail):      # chunk + move chunk axis first
-        return t.reshape((B, n_chunks, c) + tail).transpose(
-            (1, 0, 2) + tuple(range(3, 3 + len(tail))))
-
-    qc, kc, vc = csh(q, (nh, P)), csh(k, (nh, P)), csh(v, (nh, P))
-    lic, lfc = csh(logi, (nh,)), csh(logf, (nh,))
-
-    def body(state, xs):
-        c_prev, n_prev, m_prev = state
-        qi, ki, vi, li, lf = xs
-        qf = qi.astype(jnp.float32)
-        kf = ki.astype(jnp.float32)
-        vf = vi.astype(jnp.float32)
-        cum = jnp.cumsum(lf, axis=1)                       # [B,c,H] inclusive
-        # D[b,i,j,h] = cum_i - cum_j + li_j   for j <= i
-        D = cum[:, :, None, :] - cum[:, None, :, :] + li[:, None, :, :]
-        mask = jnp.tril(jnp.ones((c, c), bool))
-        D = jnp.where(mask[None, :, :, None], D, -jnp.inf)
-        # stabilizer: max over history (carried m enters via cum + m_prev)
-        m_loc = jnp.max(D, axis=2)                          # [B,i,H]
-        m_comb = jnp.maximum(m_loc, cum + m_prev[:, None, :])
-        m_comb = jnp.maximum(m_comb, -1e30)                 # avoid -inf
-        w = jnp.exp(D - m_comb[:, :, None, :])              # [B,i,j,H]
-        qk = jnp.einsum("bihp,bjhp->bijh", qf, kf)
-        s = qk * w
-        h_intra = jnp.einsum("bijh,bjhp->bihp", s, vf)
-        n_intra = jnp.einsum("bijh,bjhp->bihp", w, kf)
-        # inter-chunk contribution
-        scale_in = jnp.exp(cum + m_prev[:, None, :] - m_comb)   # [B,i,H]
-        h_inter = jnp.einsum("bihp,bhpr->bihr", qf, c_prev) * scale_in[..., None]
-        n_inter = n_prev[:, None] * scale_in[..., None]
-        h_num = h_intra + h_inter
-        n_all = n_intra + n_inter
-        denom = jnp.maximum(jnp.abs(jnp.einsum("bihp,bihp->bih", n_all, qf)),
-                            jnp.exp(-m_comb))
-        h = h_num / denom[..., None]
-        # carry update
-        total = cum[:, -1, :]                               # [B,H]
-        m_new = jnp.maximum(total + m_prev, jnp.max(
-            total[:, None, :] - cum + li, axis=1))
-        wk = jnp.exp(total[:, None, :] - cum + li - m_new[:, None, :])  # [B,c,H]
-        c_new = c_prev * jnp.exp(total + m_prev - m_new)[..., None, None] \
-            + jnp.einsum("bjhp,bjhr->bhpr", kf * wk[..., None], vf)
-        n_new = n_prev * jnp.exp(total + m_prev - m_new)[..., None] \
-            + jnp.einsum("bjhp,bjh->bhp", kf, wk)
-        return (c_new, n_new, m_new), h
-
-    st0 = (jnp.zeros((B, nh, P, P), jnp.float32),
-           jnp.zeros((B, nh, P), jnp.float32),
-           jnp.full((B, nh), -1e30, jnp.float32))
-    _, hs = jax.lax.scan(body, st0, (qc, kc, vc, lic, lfc))
-    h = hs.transpose(1, 0, 2, 3, 4).reshape(B, S, di)
+    if jax.default_backend() == "tpu":
+        from repro.kernels.mlstm_chunk.ops import mlstm_chunk
+        h = mlstm_chunk(q, k, v, logi, logf, chunk=rc.mlstm_chunk)
+    else:
+        from repro.kernels.mlstm_chunk.ref import mlstm_chunked
+        h = mlstm_chunked(q, k, v, logi, logf, chunk=rc.mlstm_chunk)
+    h = h.reshape(B, S, di)
     h = norm_apply(params["out_norm"],
                    h.astype(u.dtype) * jax.nn.silu(z.astype(jnp.float32)).astype(u.dtype),
                    kind="rmsnorm", eps=cfg.norm_eps)

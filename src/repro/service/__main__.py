@@ -16,6 +16,7 @@ from __future__ import annotations
 
 import argparse
 
+from repro.compile_cache import use_compile_cache
 from repro.service.server import TuningServer, default_catalog
 from repro.service.wire import make_wire_server
 
@@ -39,6 +40,7 @@ def main(argv=None) -> int:
     p.add_argument("--workloads", nargs="*", default=None,
                    help="restrict the hosted catalog to these names")
     args = p.parse_args(argv)
+    use_compile_cache()
 
     catalog = default_catalog()
     if args.workloads:

@@ -59,6 +59,30 @@ def test_flash_block_size_invariance():
                                    atol=1e-5)
 
 
+@pytest.mark.parametrize("case", [FLASH_CASES[0], FLASH_CASES[2],
+                                  FLASH_CASES[5]],
+                         ids=lambda c: f"B{c[0]}S{c[1]}W{c[7]}cap{c[8]}")
+def test_flash_attention_grad_matches_reference(case):
+    """The kernel's custom VJP (the chunked form's, per query block)
+    equals the gradient of the materializing oracle."""
+    B, Sq, Sk, H, Kh, D, causal, window, softcap, bq, bk = case
+    k1, k2, k3, k4 = jax.random.split(jax.random.key(Sq + 3 * H), 4)
+    q = _rand(k1, (B, Sq, H, D), jnp.float32)
+    k = _rand(k2, (B, Sk, Kh, D), jnp.float32)
+    v = _rand(k3, (B, Sk, Kh, D), jnp.float32)
+    w = _rand(k4, (B, Sq, H, D), jnp.float32)
+
+    def loss(attn, **kw):
+        return lambda q, k, v: jnp.sum(attn(q, k, v, causal=causal,
+                                            window=window, softcap=softcap,
+                                            **kw) * w)
+    got = jax.grad(loss(flash_attention, block_q=bq, block_k=bk),
+                   argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(loss(reference_attention), argnums=(0, 1, 2))(q, k, v)
+    for g, r in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r), atol=1e-4)
+
+
 MLSTM_CASES = [
     (2, 128, 2, 32, 32), (1, 256, 4, 64, 64), (2, 64, 1, 16, 16),
     (1, 512, 2, 32, 128), (1, 128, 2, 32, 128),
@@ -78,6 +102,26 @@ def test_mlstm_chunk_matches_sequential(case):
     out = mlstm_chunk(q, k, v, logi, logf, chunk=chunk)
     ref = mlstm_sequential(q, k, v, logi, logf)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=5e-5)
+
+
+def test_mlstm_chunk_grad_matches_sequential():
+    """The kernel's custom VJP (the jnp chunked form's) equals the
+    gradient of the sequential oracle, gates included."""
+    B, S, H, P, chunk = 1, 128, 2, 32, 32
+    ks = jax.random.split(jax.random.key(5), 6)
+    q = _rand(ks[0], (B, S, H, P), jnp.float32) * 0.5
+    k = _rand(ks[1], (B, S, H, P), jnp.float32) * 0.5 / (P ** 0.5)
+    v = _rand(ks[2], (B, S, H, P), jnp.float32) * 0.5
+    logi = jax.random.normal(ks[3], (B, S, H), jnp.float32)
+    logf = -jax.nn.softplus(-jax.random.normal(ks[4], (B, S, H)) * 2.0)
+    w = _rand(ks[5], (B, S, H, P), jnp.float32)
+    args = (q, k, v, logi, logf)
+    got = jax.grad(lambda *a: jnp.sum(mlstm_chunk(*a, chunk=chunk) * w),
+                   argnums=range(5))(*args)
+    want = jax.grad(lambda *a: jnp.sum(mlstm_sequential(*a) * w),
+                    argnums=range(5))(*args)
+    for g, r in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r), atol=2e-4)
 
 
 def test_mlstm_chunk_size_invariance():

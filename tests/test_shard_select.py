@@ -6,13 +6,12 @@ Guards the PR-6 tentpole contracts:
   device order (part of the pick-reproducibility contract) and the
   spare-device rule for background refits;
 * ``gp.select_batch_sharded`` picks **bit-identically** to
-  ``gp.select_batch`` on the same pool — on a 1-device mesh through both
-  entry points (``shard_map`` and the ``pmap`` CPU fallback), across
-  fantasy x acquisition, odd pool sizes (exercising the pad-to-multiple
-  rows, pre-marked taken) and the Pallas cross-Gram;
+  ``gp.select_batch`` on the same pool — on a 1-device ``shard_map``
+  mesh, across fantasy x acquisition, odd pool sizes (exercising the
+  pad-to-multiple rows, pre-marked taken) and the Pallas cross-Gram;
 * the same identity under *real* multi-device partitioning — a
   subprocess forces 2 CPU devices via ``XLA_FLAGS`` (it must be set
-  before jax imports, hence the re-exec) and checks both entry points;
+  before jax imports, hence the re-exec);
 * ``BOConfig.shard_candidates`` never changes a trace: on a 1-device
   host the gate falls back to plain ``select_batch``, and the strategy's
   picks match the gate-off run config for config.
@@ -70,7 +69,7 @@ class TestPoolMesh:
 
 
 class TestSingleDeviceIdentity:
-    """nd=1 sharded path == select_batch, both entry points.  The mesh
+    """nd=1 sharded path == select_batch.  The mesh
     machinery (padding, collective argmax, masked psum gathers) is fully
     exercised; only the cross-device traffic is degenerate."""
 
@@ -82,12 +81,10 @@ class TestSingleDeviceIdentity:
         base = np.asarray(gp.select_batch(
             st, cand, y_raw, n, best_y, 3, fantasy=fantasy,
             acquisition=acq))
-        for use_sm in (False, True):
-            picks = np.asarray(gp.select_batch_sharded(
-                st, cand, y_raw, n, best_y, 3, fantasy=fantasy,
-                acquisition=acq, use_shard_map=use_sm))
-            assert np.array_equal(base, picks), \
-                f"{fantasy}/{acq} use_shard_map={use_sm}"
+        picks = np.asarray(gp.select_batch_sharded(
+            st, cand, y_raw, n, best_y, 3, fantasy=fantasy,
+            acquisition=acq))
+        assert np.array_equal(base, picks), f"{fantasy}/{acq}"
 
     def test_q1_and_even_pool(self):
         st, y_raw, n, best_y = _problem(n=20, q=1, seed=3)
@@ -138,18 +135,16 @@ y_raw[:n] = y
 best_y = float(np.min(y))
 cand = rng.random((41, d)).astype(np.float32)   # odd: one pad row/shard
 base = np.asarray(gp.select_batch(st, cand, y_raw, n, best_y, q))
-for use_sm in (False, True):
-    picks = np.asarray(gp.select_batch_sharded(
-        st, cand, y_raw, n, best_y, q, use_shard_map=use_sm))
-    assert np.array_equal(base, picks), (use_sm, base, picks)
+picks = np.asarray(gp.select_batch_sharded(st, cand, y_raw, n, best_y, q))
+assert np.array_equal(base, picks), (base, picks)
 print("IDENTICAL", base.tolist())
 """
 
 
 class TestForcedTwoDevices:
     def test_picks_identical_across_two_devices(self):
-        """Both mesh entry points partition the pool over 2 forced CPU
-        devices and still reproduce select_batch bit for bit."""
+        """The shard_map mesh partitions the pool over 2 forced CPU
+        devices and still reproduces select_batch bit for bit."""
         env = dict(os.environ)
         env["PYTHONPATH"] = os.pathsep.join(
             p for p in (str(REPO / "src"),

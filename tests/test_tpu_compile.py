@@ -1,0 +1,142 @@
+"""The main path's kernels compile for a TPU v5e chip that is described,
+not attached.
+
+Each test lowers a kernel (or its VJP) at the width the system runs it
+and compiles it with the TPU compiler for one chip of a ``v5e:2x2``
+topology; the compiled program must hold the Mosaic kernel
+(``tpu_custom_call``), so nothing fell back to interpret mode.  Nothing
+runs: this guards what the chip's compiler refuses (block tiling, scoped
+VMEM), not results or times.
+
+The topology is described inside a fixture, never while a module is
+imported: only one process at a time may load the TPU library, and
+pytest-xdist workers import every test file.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def no_compile_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without one: keep the cache off meanwhile."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def spec(topo, no_compile_cache):
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def make(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    return make
+
+
+def _compiled_text(fn, *args) -> str:
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def test_gp_gram_compiles(spec):
+    from repro.kernels.gp_gram.ops import matern52_cross, matern52_gram
+    hlo = _compiled_text(
+        lambda x, ls: matern52_gram(x, ls, 1.0, interpret=False),
+        spec((512, 12)), spec((12,)))
+    assert "tpu_custom_call" in hlo
+    hlo = _compiled_text(
+        lambda a, b, ls: matern52_cross(a, b, ls, 1.0, interpret=False),
+        spec((4096, 12)), spec((256, 12)), spec((12,)))
+    assert "tpu_custom_call" in hlo
+
+
+def test_gp_gram_autotune_space_compiles(spec):
+    """Every tile the kernel autotuner may probe compiles at its bench
+    shape: a refused tile would be a failed probe on the chip."""
+    import itertools
+    from repro.kernels.autotune import kernel_space
+    from repro.kernels.gp_gram.ops import matern52_gram
+    sp = kernel_space("gp_gram")
+    base = sp.project(sp.default_config())
+    tiles = [(bn, bm) for bn, bm in itertools.product(
+        sp.knob("block_n").choices, sp.knob("block_m").choices)
+        if not sp.validate({**base, "block_n": bn, "block_m": bm})]
+    assert tiles
+    for bn, bm in tiles:
+        hlo = _compiled_text(
+            lambda x, ls: matern52_gram(x, ls, 1.0, block=bn, block_m=bm,
+                                        interpret=False),
+            spec((136, 8)), spec((8,)))
+        assert "tpu_custom_call" in hlo, (bn, bm)
+
+
+YI6B_ATTENTION = ((1, 4096, 32, 128), (1, 4096, 4, 128))   # q, k/v
+
+
+def test_flash_forward_compiles_at_yi6b_widths(spec):
+    from repro.kernels.flash_attention.ops import flash_attention
+    qs, ks = YI6B_ATTENTION
+    hlo = _compiled_text(
+        lambda q, k, v: flash_attention(q, k, v, interpret=False),
+        spec(qs, jnp.bfloat16), spec(ks, jnp.bfloat16),
+        spec(ks, jnp.bfloat16))
+    assert "tpu_custom_call" in hlo
+
+
+def test_flash_backward_compiles_at_yi6b_widths(spec):
+    from repro.kernels.flash_attention.ops import flash_attention
+    qs, ks = YI6B_ATTENTION
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, interpret=False) \
+            .astype(jnp.float32).sum()
+    hlo = _compiled_text(jax.value_and_grad(loss, argnums=(0, 1, 2)),
+                         spec(qs, jnp.bfloat16), spec(ks, jnp.bfloat16),
+                         spec(ks, jnp.bfloat16))
+    assert "tpu_custom_call" in hlo
+
+
+def _mlstm_args(spec):
+    # xlstm-1.3b: d_model 2048, mlstm_expand 2 -> di 4096 over 4 heads
+    B, S, H, P = 1, 4096, 4, 1024
+    return (spec((B, S, H, P), jnp.bfloat16),) * 3 + (spec((B, S, H)),) * 2
+
+
+@pytest.mark.parametrize("chunk", [256, 512])
+def test_mlstm_chunk_compiles_at_xlstm_widths(spec, chunk):
+    from repro.kernels.mlstm_chunk.ops import mlstm_chunk
+    hlo = _compiled_text(
+        lambda *a: mlstm_chunk(*a, chunk=chunk, interpret=False),
+        *_mlstm_args(spec))
+    assert "tpu_custom_call" in hlo
+
+
+def test_mlstm_chunk_backward_compiles_at_xlstm_widths(spec):
+    from repro.kernels.mlstm_chunk.ops import mlstm_chunk
+
+    def loss(*a):
+        return mlstm_chunk(*a, chunk=256, interpret=False) \
+            .astype(jnp.float32).sum()
+    hlo = _compiled_text(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4)),
+                         *_mlstm_args(spec))
+    assert "tpu_custom_call" in hlo
