@@ -1,0 +1,86 @@
+"""Shared set-up of the benchmark's own tests (run on the CPU):
+
+    JAX_PLATFORMS=cpu python -m pytest -q benchmarks/chip/tests
+
+They drive the harness's pieces directly at smoke sizes; ``run.py`` itself
+refuses any device but a TPU."""
+
+import copy
+import sys
+from pathlib import Path
+
+import pytest
+
+HERE = Path(__file__).resolve().parents[1]
+sys.path.insert(0, str(HERE))
+
+import harness  # noqa: E402
+
+harness.ensure_src_on_path()
+
+CPU_PEAK = {"bf16_flops_per_s": 1e12, "hbm_bytes_per_s": 1e11}
+
+TINY_MODELS = {
+    "yi6b.train_4k": {
+        "model": {"n_layers": 1, "d_model": 64, "n_heads": 4,
+                  "n_kv_heads": 2, "head_dim": 16, "d_ff": 128,
+                  "vocab_size": 256},
+        "overrides": {"n_layers": 1, "d_model": 64, "n_heads": 4,
+                      "n_kv_heads": 2, "d_ff": 128, "vocab_size": 256}},
+    "xlstm.train_4k": {
+        "model": {"n_layers": 8, "d_model": 128, "n_heads": 4,
+                  "n_kv_heads": 4, "vocab_size": 256},
+        "overrides": {"n_layers": 8, "d_model": 128, "n_heads": 4,
+                      "n_kv_heads": 4, "vocab_size": 256}},
+}
+TINY_TRAIN = {"seq": 128, "batch": 2, "pool": 4, "doc_len": 32}
+TINY_TUNE = {"n_rank_samples": 40, "check_asks": 4, "check_rankings": 2,
+             "bo": {"n_init": 6, "n_iter": 16, "n_candidates": 128,
+                    "n_local": 32, "fit_steps": 30, "use_pallas": True}}
+
+
+# The tuning-session cell waits, out of BENCHMARK.json, on a fault of the
+# program (PERF.md, Open questions); its entry and end-to-end metrics as
+# they will be listed, so that its driver stays tested.
+TUNE_ENTRY = {"name": "yi6b.tune_256", "config": "yi-6b.pod256",
+              "traffic": "tune_256", "chips": 1}
+TUNE_E2E = [{"name": "tune_evals_per_s", "unit": "evals/s",
+             "workloads": ["yi6b.tune_256"]},
+            {"name": "tune_ask_ms_p95", "unit": "ms",
+             "workloads": ["yi6b.tune_256"]}]
+
+
+class TinyCell(harness.Cell):
+    """A cell of BENCHMARK.json, or the tuning-session cell, at a smoke
+    size."""
+
+    def __init__(self, name, limits=None):
+        bench = harness.load_json(harness.ROOT / "BENCHMARK.json")
+        entry = None
+        if name == TUNE_ENTRY["name"]:
+            entry = TUNE_ENTRY
+            bench = {**bench, "end_to_end": bench["end_to_end"] + TUNE_E2E}
+        super().__init__(name, bench=bench, entry=entry, limits=limits)
+        self.config = copy.deepcopy(self.config)
+        self.traffic = copy.deepcopy(self.traffic)
+        if name in TINY_MODELS:
+            self.config["model"].update(TINY_MODELS[name]["model"])
+            self.config["overrides"] = TINY_MODELS[name]["overrides"]
+            self.traffic.update(TINY_TRAIN)
+            if "mlstm_chunk" in self.traffic["knobs"]:
+                self.traffic["knobs"]["mlstm_chunk"] = 64
+        else:
+            self.traffic.update(TINY_TUNE)
+
+
+@pytest.fixture
+def cpu_peak(monkeypatch):
+    monkeypatch.setattr(harness, "peak", lambda kind: CPU_PEAK)
+
+
+def load_driver(name):
+    mod = f"drivers_{name}"
+    if mod not in sys.modules:
+        sys.modules[mod] = harness.load_module(
+            HERE / "drivers" / f"{name}.py", mod)
+    return sys.modules[mod]
