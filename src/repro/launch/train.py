@@ -8,6 +8,11 @@ mesh; on a fleet the same driver runs the full config on the production
 mesh (launch/mesh.py).  Integrates the whole runtime: RunConfig knobs,
 sharded train step, stateless data stream, checkpoint/auto-resume, and
 the step-time watchdog feeding the elastic policy.
+
+After the first step it logs one line of compile counters
+(``repro.compile_cache.watch_compiles``): the programs compiled so far,
+their trace, lowering and compile seconds, and the persistent cache's
+hits and misses.
 """
 
 from __future__ import annotations
@@ -17,7 +22,7 @@ import time
 
 import jax
 
-from repro.compile_cache import use_compile_cache
+from repro.compile_cache import use_compile_cache, watch_compiles
 from repro.configs import get_config, get_smoke_config
 from repro.launch.mesh import make_host_mesh
 from repro.models.model import Model
@@ -63,6 +68,8 @@ def main(argv=None):
                     help="RunConfig override, e.g. --knob microbatch=2")
     args = ap.parse_args(argv)
     use_compile_cache()
+    compiles = watch_compiles()
+    t_start = time.time()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     rc = runconfig_from_knobs(parse_knobs(args.knob))
@@ -95,6 +102,11 @@ def main(argv=None):
                 print(f"step {i+1:5d} loss {float(mets['loss']):.4f} "
                       f"gnorm {float(mets['grad_norm']):.3f} "
                       f"lr {float(mets['lr']):.2e}")
+            if i == start:
+                c = compiles.snapshot(since=t_start)
+                print(f"compiled {c.programs} programs in {c.seconds:.2f} s "
+                      f"(persistent cache: {c.cache_hits} hits, "
+                      f"{c.cache_misses} misses)")
             if cm and (i + 1) % args.ckpt_every == 0:
                 cm.save(i + 1, state, blocking=False)
         if cm:

@@ -24,8 +24,8 @@ import jax.numpy as jnp
 from repro.models import attention, mlp, moe, ssm, xlstm
 from repro.models.common import (dense_apply, norm_apply, norm_axes,
                                  norm_init, stack_axes, stack_init, trunc_normal)
-from repro.models.config import (ATTN, MAMBA, MLP_DENSE, MLP_MOE, MLSTM,
-    SLSTM, LayerSpec, ModelConfig)
+from repro.models.config import (ATTN, MAMBA, MLP_DENSE, MLP_MOE, MLP_NONE,
+    MLSTM, SLSTM, LayerSpec, ModelConfig)
 from jax.ad_checkpoint import checkpoint_name
 
 from repro.runconfig import RunConfig
@@ -118,28 +118,39 @@ def axes(cfg: ModelConfig):
 # full-sequence forward (train / prefill)
 # ---------------------------------------------------------------------------
 
+_MLP_SCOPE = {MLP_DENSE: "model.mlp", MLP_MOE: "model.moe"}
+
+
 def _block_forward(spec: LayerSpec, p, x, positions, cfg: ModelConfig,
                    rc: RunConfig):
-    """One block (pre-norm residual).  Returns (x, aux_loss)."""
+    """One block (pre-norm residual).  Returns (x, aux_loss).
+
+    The mixer and the MLP branch each run under a ``jax.named_scope``
+    (``model.<kind>``, ``model.mlp`` / ``model.moe``): it names their
+    operations, forward and backward, in the compiled program's metadata
+    and so in a profile."""
     aux = jnp.zeros((), jnp.float32)
-    h = norm_apply(p["norm1"], x, kind=cfg.norm, eps=cfg.norm_eps)
-    if spec.kind == ATTN:
-        h = attention.apply(p["attn"], h, positions, cfg, rc,
-                            causal=True, window=spec.sliding_window)
-    elif spec.kind == MAMBA:
-        h = ssm.apply(p["mamba"], h, cfg, rc)
-    elif spec.kind == MLSTM:
-        h = xlstm.mlstm_apply(p["mlstm"], h, cfg, rc)
-    elif spec.kind == SLSTM:
-        h = xlstm.slstm_apply(p["slstm"], h, cfg, rc)
-    x = x + h
-    if spec.mlp == MLP_DENSE:
+    with jax.named_scope(f"model.{spec.kind}"):
+        h = norm_apply(p["norm1"], x, kind=cfg.norm, eps=cfg.norm_eps)
+        if spec.kind == ATTN:
+            h = attention.apply(p["attn"], h, positions, cfg, rc,
+                                causal=True, window=spec.sliding_window)
+        elif spec.kind == MAMBA:
+            h = ssm.apply(p["mamba"], h, cfg, rc)
+        elif spec.kind == MLSTM:
+            h = xlstm.mlstm_apply(p["mlstm"], h, cfg, rc)
+        elif spec.kind == SLSTM:
+            h = xlstm.slstm_apply(p["slstm"], h, cfg, rc)
+        x = x + h
+    if spec.mlp == MLP_NONE:
+        return x, aux
+    with jax.named_scope(_MLP_SCOPE[spec.mlp]):
         h = norm_apply(p["norm2"], x, kind=cfg.norm, eps=cfg.norm_eps)
-        x = x + mlp.apply(p["mlp"], h, cfg, rc)
-    elif spec.mlp == MLP_MOE:
-        h = norm_apply(p["norm2"], x, kind=cfg.norm, eps=cfg.norm_eps)
-        y, aux = moe.apply(p["moe"], h, cfg, rc)
-        x = x + y
+        if spec.mlp == MLP_DENSE:
+            x = x + mlp.apply(p["mlp"], h, cfg, rc)
+        else:
+            y, aux = moe.apply(p["moe"], h, cfg, rc)
+            x = x + y
     return x, aux
 
 
@@ -200,25 +211,26 @@ def backbone(params, x, positions, cfg: ModelConfig, rc: RunConfig):
 
 
 def embed(params, tokens, cfg: ModelConfig):
-    x = params["embed"]["tok"][tokens]
-    if cfg.embedding_multiplier:
-        x = x * jnp.asarray(cfg.embedding_multiplier, x.dtype)
+    with jax.named_scope("model.embed"):
+        x = params["embed"]["tok"][tokens]
+        if cfg.embedding_multiplier:
+            x = x * jnp.asarray(cfg.embedding_multiplier, x.dtype)
     return x
 
 
 def unembed(params, x, cfg: ModelConfig, rc: Optional[RunConfig] = None):
-    if cfg.tie_embeddings:
-        w = params["embed"]["tok"].T
-    else:
-        w = params["head"]["w"]
     from repro.models.common import dense_apply, reduce_dtype
-    if rc is not None and reduce_dtype(rc) == jnp.bfloat16:
-        # vocab-sharded head: bwd dgrad AR in bf16
-        return dense_apply({"w": w}, x, preferred=jnp.bfloat16) \
-            .astype(jnp.float32)
-    logits = jnp.einsum("bsd,dv->bsv", x, w,
-                        preferred_element_type=jnp.float32)
-    return logits
+    with jax.named_scope("model.head"):
+        if cfg.tie_embeddings:
+            w = params["embed"]["tok"].T
+        else:
+            w = params["head"]["w"]
+        if rc is not None and reduce_dtype(rc) == jnp.bfloat16:
+            # vocab-sharded head: bwd dgrad AR in bf16
+            return dense_apply({"w": w}, x, preferred=jnp.bfloat16) \
+                .astype(jnp.float32)
+        return jnp.einsum("bsd,dv->bsv", x, w,
+                          preferred_element_type=jnp.float32)
 
 
 def forward(params, tokens, cfg: ModelConfig, rc: RunConfig,
@@ -241,12 +253,14 @@ def loss_fn(params, batch: Dict[str, jnp.ndarray], cfg: ModelConfig,
     logits, aux = forward(params, batch["tokens"], cfg, rc,
                           positions=batch.get("positions"))
     labels = batch["labels"]
-    logz = jax.nn.logsumexp(logits, axis=-1)
-    # one-hot contraction instead of gather: partial-sums cleanly over a
-    # vocab-sharded (model-axis) logits tensor in SPMD.
-    onehot = jax.nn.one_hot(labels, cfg.vocab_size, dtype=logits.dtype)
-    ll = jnp.einsum("bsv,bsv->bs", logits, onehot)
-    nll = jnp.mean(logz - ll)
+    # the cross-entropy shares the head's scope with the unembedding
+    with jax.named_scope("model.head"):
+        logz = jax.nn.logsumexp(logits, axis=-1)
+        # one-hot contraction instead of gather: partial-sums cleanly over a
+        # vocab-sharded (model-axis) logits tensor in SPMD.
+        onehot = jax.nn.one_hot(labels, cfg.vocab_size, dtype=logits.dtype)
+        ll = jnp.einsum("bsv,bsv->bs", logits, onehot)
+        nll = jnp.mean(logz - ll)
     return nll + aux, {"nll": nll, "aux": aux}
 
 

@@ -86,51 +86,53 @@ def make_train_step(model: Model, rc: RunConfig,
         n_micro = max(per_replica // min(micro, per_replica), 1)
         n_micro = min(n_micro, b)            # b must split into n_micro
 
-        grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
+        with jax.named_scope("train.grad"):
+            grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
 
-        if n_micro == 1 or b % n_micro != 0:
-            (loss, metrics), grads = grad_fn(state.params, batch)
-            grads = jax.tree.map(lambda g: g.astype(grad_dtype), grads)
-        else:
-            mbs = _split_micro(batch, n_micro)
-
-            def accum(carry, mb):
-                g_acc, l_acc = carry
-                (loss, _), g = grad_fn(state.params, mb)
-                g = jax.tree.map(lambda x: x.astype(grad_dtype), g)
-                # per-microbatch reduction: accumulate in the (possibly
-                # compressed) reduction dtype right away — the pattern XLA
-                # overlaps; bulk mode accumulates f32 and casts at the end.
-                if rc.allreduce_per_microbatch:
-                    g_acc = jax.tree.map(lambda a, x: a + x, g_acc, g)
-                else:
-                    g_acc = jax.tree.map(
-                        lambda a, x: a + x.astype(jnp.float32), g_acc, g)
-                return (g_acc, l_acc + loss), None
-
-            acc_dtype = grad_dtype if rc.allreduce_per_microbatch \
-                else jnp.float32
-            g0 = jax.tree.map(lambda p: jnp.zeros(p.shape, acc_dtype),
-                              state.params)
-            if rc.grad_accum_unroll:
-                carry = (g0, jnp.zeros((), jnp.float32))
-                for i in range(n_micro):
-                    mb = jax.tree.map(lambda x: x[i], mbs)
-                    carry, _ = accum(carry, mb)
-                grads_sum, loss_sum = carry
+            if n_micro == 1 or b % n_micro != 0:
+                (loss, metrics), grads = grad_fn(state.params, batch)
+                grads = jax.tree.map(lambda g: g.astype(grad_dtype), grads)
             else:
-                (grads_sum, loss_sum), _ = jax.lax.scan(
-                    accum, (g0, jnp.zeros((), jnp.float32)), mbs)
-            grads = jax.tree.map(
-                lambda g: (g.astype(jnp.float32) / n_micro).astype(grad_dtype),
-                grads_sum)
-            loss = loss_sum / n_micro
-            metrics = {"nll": loss, "aux": jnp.zeros((), jnp.float32)}
+                mbs = _split_micro(batch, n_micro)
+
+                def accum(carry, mb):
+                    g_acc, l_acc = carry
+                    (loss, _), g = grad_fn(state.params, mb)
+                    g = jax.tree.map(lambda x: x.astype(grad_dtype), g)
+                    # per-microbatch reduction: accumulate in the (possibly
+                    # compressed) reduction dtype right away — the pattern XLA
+                    # overlaps; bulk mode accumulates f32 and casts at the end.
+                    if rc.allreduce_per_microbatch:
+                        g_acc = jax.tree.map(lambda a, x: a + x, g_acc, g)
+                    else:
+                        g_acc = jax.tree.map(
+                            lambda a, x: a + x.astype(jnp.float32), g_acc, g)
+                    return (g_acc, l_acc + loss), None
+
+                acc_dtype = grad_dtype if rc.allreduce_per_microbatch \
+                    else jnp.float32
+                g0 = jax.tree.map(lambda p: jnp.zeros(p.shape, acc_dtype),
+                                  state.params)
+                if rc.grad_accum_unroll:
+                    carry = (g0, jnp.zeros((), jnp.float32))
+                    for i in range(n_micro):
+                        mb = jax.tree.map(lambda x: x[i], mbs)
+                        carry, _ = accum(carry, mb)
+                    grads_sum, loss_sum = carry
+                else:
+                    (grads_sum, loss_sum), _ = jax.lax.scan(
+                        accum, (g0, jnp.zeros((), jnp.float32)), mbs)
+                grads = jax.tree.map(
+                    lambda g: (g.astype(jnp.float32) / n_micro).astype(grad_dtype),
+                    grads_sum)
+                loss = loss_sum / n_micro
+                metrics = {"nll": loss, "aux": jnp.zeros((), jnp.float32)}
 
         lr = lr_schedule(state.step)
-        new_params, new_opt = opt.opt_update(grads, state.opt_state,
-                                             state.params, rc, lr)
-        gnorm = opt.global_norm(grads)
+        with jax.named_scope("train.update"):
+            new_params, new_opt = opt.opt_update(grads, state.opt_state,
+                                                 state.params, rc, lr)
+            gnorm = opt.global_norm(grads)
         out_metrics = {"loss": loss.astype(jnp.float32),
                        "grad_norm": gnorm, "lr": lr,
                        **{k: v.astype(jnp.float32)

@@ -140,3 +140,34 @@ def test_mlstm_chunk_backward_compiles_at_xlstm_widths(spec):
     hlo = _compiled_text(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4)),
                          *_mlstm_args(spec))
     assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("kernel", ["flash_attention", "mlstm_chunk"])
+def test_kernel_backward_keeps_its_scope(spec, kernel):
+    """Under a caller's ``jax.named_scope``, every operation of the
+    kernel's gradient compiled for the chip names that scope, as backward
+    (its path holds ``transpose(``): a profile attributes the custom VJP
+    to the layer that called the kernel."""
+    import re
+    from repro.kernels.flash_attention.ops import flash_attention
+    from repro.kernels.mlstm_chunk.ops import mlstm_chunk
+    q, kv = spec((1, 256, 2, 128)), spec((1, 256, 1, 128))
+    args, scope = (q, kv, kv), "model.attn"
+    if kernel == "mlstm_chunk":
+        args, scope = (q, q, q, spec((1, 256, 2)), spec((1, 256, 2))), \
+            "model.mlstm"
+
+    def loss(*a):
+        with jax.named_scope(scope):
+            if kernel == "flash_attention":
+                out = flash_attention(*a, interpret=False, block_q=128,
+                                      block_k=128)
+            else:
+                out = mlstm_chunk(*a, chunk=128, interpret=False)
+        return out.sum()
+    hlo = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)), *args)
+    paths = [p for p in re.findall(r'op_name="([^"]*)"', hlo)
+             if p.startswith("jit(")]
+    assert paths
+    assert all(f"transpose(jvp({scope}))" in p for p in paths), \
+        sorted({p for p in paths if scope not in p})[:5]
