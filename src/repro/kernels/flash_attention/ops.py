@@ -2,10 +2,13 @@
 
 ``flash_attention(q, k, v)`` takes the model-zoo layout [B, S, H, D] /
 [B, S, Kh, D], pads sequence lengths up to the block grid, flattens heads,
-runs the Pallas kernel (interpret mode off-TPU) and restores the layout.
-The kernel computes the forward only; its VJP is that of the chunked
-online-softmax form it matches (``ref.chunked_attention``), so the train
-step can differentiate through the kernel.
+runs the Pallas kernels (interpret mode off-TPU) and restores the layout.
+It is differentiable: a custom VJP on the flat, padded layout saves the
+forward's output and per-row log-sum-exp, computes δ = rowsum(dO∘O) in
+jnp, and runs the two Pallas backward kernels
+(``kernel.flash_attention_bwd``), which skip the masked tiles as the
+forward does.  Autodiff of the padding and layout changes around it
+returns the gradients in the model layout.
 """
 
 from __future__ import annotations
@@ -16,8 +19,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.flash_attention.kernel import flash_attention_fwd
-from repro.kernels.flash_attention.ref import chunked_attention
+from repro.kernels.flash_attention.kernel import (flash_attention_bwd,
+                                                  flash_attention_fwd)
 
 
 def _pad_to(x, target: int, axis: int):
@@ -43,18 +46,12 @@ def flash_attention(q, k, v, *, causal: bool = True,
     """q [B,Sq,H,D], k/v [B,Sk,Kh,D] -> [B,Sq,H,D] (q.dtype).
 
     ``block_q``/``block_k``/``num_warps``/``pipeline`` are SAPPHIRE
-    autotune knobs (:func:`autotune_space`); the output is
-    tiling-invariant (tests/test_kernels.py guards this).  Differentiable
-    (see the module docstring).
+    autotune knobs (:func:`autotune_space`); the output and its gradient
+    are tiling-invariant (tests/test_kernels.py guards this).
+    Differentiable (see the module docstring).
     """
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    return _flash(q, k, v, causal, window, softcap, block_q, block_k,
-                  num_warps, pipeline, interpret)
-
-
-def _forward(q, k, v, causal, window, softcap, block_q, block_k, num_warps,
-             pipeline, interpret):
     B, Sq, H, D = q.shape
     _, Sk, Kh, _ = k.shape
 
@@ -63,64 +60,42 @@ def _forward(q, k, v, causal, window, softcap, block_q, block_k, num_warps,
     sq_pad = _round_up(Sq, bq)
     sk_pad = _round_up(Sk, bk)
 
-    qf = _pad_to(q, sq_pad, 1).transpose(0, 2, 1, 3).reshape(B * H, sq_pad, D)
-    kf = _pad_to(k, sk_pad, 1).transpose(0, 2, 1, 3).reshape(B * Kh, sk_pad, D)
-    vf = _pad_to(v, sk_pad, 1).transpose(0, 2, 1, 3).reshape(B * Kh, sk_pad, D)
+    def flat(x, s_pad):
+        x = _pad_to(x, s_pad, 1).transpose(0, 2, 1, 3)
+        return x.reshape(-1, s_pad, D)
 
-    o = flash_attention_fwd(qf, kf, vf, causal=causal, window=window,
-                            softcap=softcap, block_q=bq, block_k=bk,
-                            num_warps=num_warps, pipeline=pipeline,
-                            sq_valid=Sq, sk_valid=Sk, interpret=interpret)
+    o = _flash(flat(q, sq_pad), flat(k, sk_pad), flat(v, sk_pad), causal,
+               window, softcap, bq, bk, Sq, Sk, num_warps, pipeline,
+               interpret)
     o = o.reshape(B, H, sq_pad, D).transpose(0, 2, 1, 3)
     return o[:, :Sq]
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=tuple(range(3, 11)))
-def _flash(q, k, v, causal, window, softcap, block_q, block_k, num_warps,
-           pipeline, interpret):
-    return _forward(q, k, v, causal, window, softcap, block_q, block_k,
-                    num_warps, pipeline, interpret)
+def _kernel_args(causal, window, softcap, bq, bk, sq_valid, sk_valid,
+                 num_warps, pipeline, interpret):
+    return dict(causal=causal, window=window, softcap=softcap, block_q=bq,
+                block_k=bk, sq_valid=sq_valid, sk_valid=sk_valid,
+                num_warps=num_warps, pipeline=pipeline, interpret=interpret)
 
 
-def _flash_fwd(q, k, v, causal, window, softcap, block_q, block_k,
-               num_warps, pipeline, interpret):
-    out = _forward(q, k, v, causal, window, softcap, block_q, block_k,
-                   num_warps, pipeline, interpret)
-    return out, (q, k, v)
+@functools.partial(jax.custom_vjp, nondiff_argnums=tuple(range(3, 13)))
+def _flash(q, k, v, *args):
+    return flash_attention_fwd(q, k, v, **_kernel_args(*args))
 
 
-def _flash_bwd(causal, window, softcap, block_q, block_k, num_warps,
-               pipeline, interpret, res, g):
-    """VJP of the chunked form, one query block at a time: attention rows
-    are independent, so each block's VJP (keys offset by the block start)
-    is exact, and the saved softmax tiles are [block_q, Sk] instead of
-    [Sq, Sk].  f32 operands keep the per-block dk/dv unrounded."""
-    q, k, v = res
-    B, Sq, H, D = q.shape
-    bq = min(block_q, Sq)
-    n_qb = -(-Sq // bq)
-    f32 = jnp.float32
+def _flash_fwd(q, k, v, *args):
+    o, lse = flash_attention_fwd(q, k, v, with_lse=True,
+                                 **_kernel_args(*args))
+    return o, (q, k, v, o, lse)
 
-    def blocks(x):
-        x = _pad_to(x.astype(f32), n_qb * bq, 1)
-        return x.reshape(B, n_qb, bq, H, D).transpose(1, 0, 2, 3, 4)
 
-    def body(carry, xs):
-        dk, dv = carry
-        i, qb, gb = xs
-        _, vjp = jax.vjp(
-            lambda qb, kf, vf: chunked_attention(
-                qb, kf, vf, causal=causal, window=window, softcap=softcap,
-                chunk=block_k, offset=i * bq),
-            qb, k.astype(f32), v.astype(f32))
-        dq_b, dk_b, dv_b = vjp(gb)
-        return (dk + dk_b, dv + dv_b), dq_b
-
-    zeros = (jnp.zeros(k.shape, f32), jnp.zeros(v.shape, f32))
-    (dk, dv), dq = jax.lax.scan(body, zeros,
-                                (jnp.arange(n_qb), blocks(q), blocks(g)))
-    dq = dq.transpose(1, 0, 2, 3, 4).reshape(B, n_qb * bq, H, D)[:, :Sq]
-    return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
+def _flash_bwd(*args):
+    # custom_vjp passes the nondiff arguments, the residuals, the cotangent
+    *args, (q, k, v, o, lse), g = args
+    delta = jnp.sum(o.astype(jnp.float32) * g.astype(jnp.float32),
+                    axis=-1)[:, None, :]
+    return flash_attention_bwd(q, k, v, g.astype(q.dtype), lse, delta,
+                               **_kernel_args(*args))
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)

@@ -1,7 +1,6 @@
-"""Pure-jnp oracles for the flash-attention kernel: the plain softmax
+"""Pure-jnp oracles for the flash-attention kernels: the plain softmax
 attention (materializes scores) and the chunked online-softmax form that
-never does (its VJP is the kernel's backward, and it is the model's
-compiled path off the TPU)."""
+never does (the model's compiled path off the TPU)."""
 
 from __future__ import annotations
 
@@ -41,7 +40,7 @@ def reference_attention(q, k, v, *, causal: bool = True,
 
 
 def chunked_attention(q, k, v, *, causal: bool, window: Optional[int],
-                      softcap: Optional[float], chunk: int, offset: int = 0):
+                      softcap: Optional[float], chunk: int):
     """Online-softmax over KV chunks; O(Sq·chunk) live memory.
 
     Equivalent to reference_attention (tests assert allclose); this is the
@@ -74,7 +73,7 @@ def chunked_attention(q, k, v, *, causal: bool, window: Optional[int],
         # materialized for every chunk (512 MB-scale buffers per layer);
         # the additive form stays 2-D and fuses into the einsum epilogue.
         kidx = ci * chunk + jnp.arange(chunk)
-        qidx = jnp.arange(Sq) + offset
+        qidx = jnp.arange(Sq)
         neg = jnp.where(kidx[None, :] < Sk, 0.0, NEG_INF)       # pad
         if causal:
             neg = neg + jnp.where(kidx[None, :] <= qidx[:, None], 0.0,

@@ -100,7 +100,7 @@ def reference_attention(q, k, v, *, causal: bool, window: Optional[int],
 
 def flash_attention_dispatch(q, k, v, *, causal, window, softcap, rc: RunConfig):
     """Route to the Pallas kernel on TPU (differentiable: its backward is
-    the chunked path's VJP); chunked equivalent elsewhere."""
+    the Pallas dK/dV and dQ kernels); chunked equivalent elsewhere."""
     backend = jax.default_backend()
     if backend == "tpu":
         from repro.kernels.flash_attention import ops as flash_ops

@@ -59,26 +59,81 @@ def test_flash_block_size_invariance():
                                    atol=1e-5)
 
 
-@pytest.mark.parametrize("case", [FLASH_CASES[0], FLASH_CASES[2],
-                                  FLASH_CASES[5]],
-                         ids=lambda c: f"B{c[0]}S{c[1]}W{c[7]}cap{c[8]}")
-def test_flash_attention_grad_matches_reference(case):
-    """The kernel's custom VJP (the chunked form's, per query block)
-    equals the gradient of the materializing oracle."""
+def _flash_grads(attn, q, k, v, w, **kw):
+    def loss(q, k, v):
+        return jnp.sum(attn(q, k, v, **kw).astype(jnp.float32)
+                       * w.astype(jnp.float32))
+    return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+
+@pytest.mark.parametrize("case", FLASH_CASES,
+                         ids=lambda c: f"B{c[0]}S{c[1]}x{c[2]}H{c[3]}-{c[4]}"
+                                       f"W{c[7]}cap{c[8]}")
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_flash_attention_grad_matches_reference(case, dtype):
+    """The kernel's custom VJP (the Pallas dK/dV and dQ kernels) equals
+    the gradient of the materializing f32 oracle at the same inputs.
+
+    f32: within 1e-4.  bf16: within 1e-2 of each gradient's largest
+    reference magnitude — the gradients are returned in bf16 (2^-9
+    relative rounding) and P and dS enter the MXU as bf16; the worst
+    case reads 0.5 % in interpret mode."""
     B, Sq, Sk, H, Kh, D, causal, window, softcap, bq, bk = case
     k1, k2, k3, k4 = jax.random.split(jax.random.key(Sq + 3 * H), 4)
-    q = _rand(k1, (B, Sq, H, D), jnp.float32)
-    k = _rand(k2, (B, Sk, Kh, D), jnp.float32)
-    v = _rand(k3, (B, Sk, Kh, D), jnp.float32)
-    w = _rand(k4, (B, Sq, H, D), jnp.float32)
+    q = _rand(k1, (B, Sq, H, D), dtype)
+    k = _rand(k2, (B, Sk, Kh, D), dtype)
+    v = _rand(k3, (B, Sk, Kh, D), dtype)
+    w = _rand(k4, (B, Sq, H, D), dtype)
+    kw = dict(causal=causal, window=window, softcap=softcap)
+    got = _flash_grads(flash_attention, q, k, v, w, block_q=bq, block_k=bk,
+                       **kw)
+    f32 = [x.astype(jnp.float32) for x in (q, k, v)]
+    want = _flash_grads(reference_attention, *f32, w, **kw)
+    for g, r in zip(got, want):
+        assert g.dtype == dtype
+        r = np.asarray(r)
+        tol = 1e-4 if dtype == jnp.float32 else 1e-2 * np.abs(r).max()
+        np.testing.assert_allclose(np.asarray(g, np.float32), r, atol=tol)
 
-    def loss(attn, **kw):
-        return lambda q, k, v: jnp.sum(attn(q, k, v, causal=causal,
-                                            window=window, softcap=softcap,
-                                            **kw) * w)
-    got = jax.grad(loss(flash_attention, block_q=bq, block_k=bk),
-                   argnums=(0, 1, 2))(q, k, v)
-    want = jax.grad(loss(reference_attention), argnums=(0, 1, 2))(q, k, v)
+
+_GRAD_TILES = [(64, 64), (128, 256), (256, 128)]
+
+
+@pytest.mark.parametrize("tiles", _GRAD_TILES, ids=lambda t: f"{t[0]}x{t[1]}")
+def test_flash_grad_block_size_invariance(tiles):
+    """The gradient must not depend on the (tuned) block sizes: each
+    tiling, with its skipped tiles and clamped block indices, matches the
+    one-block gradient."""
+    k1, k2, k3, k4 = jax.random.split(jax.random.key(7), 4)
+    q = _rand(k1, (1, 256, 4, 64), jnp.float32)
+    k = _rand(k2, (1, 256, 2, 64), jnp.float32)
+    v = _rand(k3, (1, 256, 2, 64), jnp.float32)
+    w = _rand(k4, (1, 256, 4, 64), jnp.float32)
+    bq, bk = tiles
+    got = _flash_grads(flash_attention, q, k, v, w, block_q=bq, block_k=bk)
+    one = _flash_grads(flash_attention, q, k, v, w, block_q=256,
+                       block_k=256)
+    for g, r in zip(got, one):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r), atol=1e-5)
+
+
+@pytest.mark.parametrize("tiles", [(64, 64), (64, 128), (128, 64)],
+                         ids=lambda t: f"{t[0]}x{t[1]}")
+def test_flash_grad_window_skips_tiles(tiles):
+    """A window of 64 over 512 positions leaves most tiles wholly outside
+    it, so the window sides of the backward's clamps (the first KV block
+    a query block sees, the last query block a KV block is seen by)
+    redirect skipped steps; the visible ones must still read their own
+    blocks.  f32 against the oracle within 1e-4."""
+    k1, k2, k3, k4 = jax.random.split(jax.random.key(11), 4)
+    q = _rand(k1, (1, 512, 4, 32), jnp.float32)
+    k = _rand(k2, (1, 512, 2, 32), jnp.float32)
+    v = _rand(k3, (1, 512, 2, 32), jnp.float32)
+    w = _rand(k4, (1, 512, 4, 32), jnp.float32)
+    bq, bk = tiles
+    got = _flash_grads(flash_attention, q, k, v, w, window=64, block_q=bq,
+                       block_k=bk)
+    want = _flash_grads(reference_attention, q, k, v, w, window=64)
     for g, r in zip(got, want):
         np.testing.assert_allclose(np.asarray(g), np.asarray(r), atol=1e-4)
 

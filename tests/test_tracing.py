@@ -142,23 +142,31 @@ def _mlstm_loss(q, k, v):
         return mlstm_chunk(q, k, v, gate, -gate, chunk=16).sum()
 
 
-@pytest.mark.parametrize("loss,scope,kv_heads", [
-    (_flash_loss, "model.attn", 1), (_mlstm_loss, "model.mlstm", 2)])
-def test_kernel_vjp_is_named_backward(loss, scope, kv_heads):
+@pytest.mark.parametrize("loss,scope,kv_heads,saves_forward", [
+    (_flash_loss, "model.attn", 1, True),
+    (_mlstm_loss, "model.mlstm", 2, False)])
+def test_kernel_vjp_is_named_backward(loss, scope, kv_heads, saves_forward):
     """The kernels' custom VJPs (run in interpret mode here) keep the
     caller's scope, and their backward rules read as backward: every
-    operation the gradient's program names lies under the scope, as
-    backward.  (The CPU compiler drops the metadata of the products it
-    rewrites in the interpreted flash kernel: those name nothing.  For
-    the chip, see tests/test_tpu_compile.py.)"""
+    operation the gradient's program names lies under the scope.  The
+    mLSTM VJP recomputes from its inputs, so all of its gradient is
+    backward; the flash VJP saves its forward's output and log-sum-exp,
+    so its gradient also runs the forward kernel, which reads as forward
+    and lies inside the kernel's wrapper.  (The CPU compiler drops the
+    metadata of the products it rewrites in the interpreted flash
+    kernels: those name nothing.  For the chip, see
+    tests/test_tpu_compile.py.)"""
     q = jnp.ones((1, 64, 2, 16))
     kv = jnp.ones((1, 64, kv_heads, 16))
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, kv, kv) \
         .compile().as_text()
     paths = [p for p in OP_NAME.findall(text) if p.startswith("jit(")]
     assert paths
-    assert {_scope(p) for p in paths} == {(scope, "bwd")}
-    assert _heavy_scopes(text).keys() - {None} <= {(scope, "bwd")}
+    want = {(scope, "bwd")} | ({(scope, "fwd")} if saves_forward else set())
+    assert {_scope(p) for p in paths} == want
+    assert all("jit(flash_attention)" in p for p in paths
+               if _scope(p) == (scope, "fwd"))
+    assert _heavy_scopes(text).keys() - {None} <= want
 
 
 def test_counters_count_a_backend_compile():
