@@ -23,6 +23,7 @@ of the changes.
 
 from __future__ import annotations
 
+import dataclasses
 import math
 import shutil
 import time
@@ -44,24 +45,45 @@ TRAIN_E2E = "train_tokens_per_s"
 # the configuration and run configuration as stated in the cell's files
 # ---------------------------------------------------------------------------
 
-def model_config(cell):
-    from repro.configs import get_config
+def _run_value(cfg, key: str):
+    """What the system runs for a key of a configuration file's ``model``:
+    the field of that name, a field of every pattern position as a list
+    (``mlp``, ``sliding_window``; ``pattern`` is their ``kind``), or one of
+    the derived widths below.  ``KeyError`` where it has none."""
+    from repro.models.config import LayerSpec
     from repro.models.xlstm import N_SLSTM_BLOCKS
+    derived = {"head_dim": lambda: cfg.resolved_head_dim,
+               "pattern": lambda: [s.kind for s in cfg.pattern],
+               "slstm_blocks": lambda: N_SLSTM_BLOCKS}
+    if key in derived:
+        return derived[key]()
+    if key in {f.name for f in dataclasses.fields(LayerSpec)}:
+        return [getattr(s, key) for s in cfg.pattern]
+    if key in {f.name for f in dataclasses.fields(cfg)}:
+        v = getattr(cfg, key)
+        return list(v) if isinstance(v, tuple) else v
+    raise KeyError(key)
+
+
+def model_config(cell):
+    """The system's configuration for the cell's file: ``arch`` with the
+    file's ``overrides``, every key the file states under ``model``
+    compared with what the system runs."""
+    from repro.configs import get_config
     conf = cell.config
     cfg = get_config(conf["arch"]).scaled(**conf["overrides"])
-    stated = conf["model"]
-    actual = {"pattern": [s.kind for s in cfg.pattern],
-              "slstm_blocks": N_SLSTM_BLOCKS,
-              "n_layers": cfg.n_layers, "d_model": cfg.d_model,
-              "n_heads": cfg.n_heads, "n_kv_heads": cfg.n_kv_heads,
-              "head_dim": cfg.resolved_head_dim, "d_ff": cfg.d_ff,
-              "vocab_size": cfg.vocab_size, "rope_theta": cfg.rope_theta,
-              "norm_eps": cfg.norm_eps, "act": cfg.act,
-              "tie_embeddings": cfg.tie_embeddings,
-              "mlstm_expand": cfg.mlstm_expand,
-              "slstm_proj": cfg.slstm_proj}
-    wrong = {k: (v, actual[k]) for k, v in stated.items()
-             if k in actual and actual[k] != v}
+    wrong, missing = {}, []
+    for k, v in conf["model"].items():
+        try:
+            run = _run_value(cfg, k)
+        except KeyError:
+            missing.append(k)
+            continue
+        if run != v:
+            wrong[k] = (v, run)
+    if missing:
+        raise ValueError(f"{conf['name']}: the system has no setting for "
+                         f"the stated keys {missing}")
     if wrong:
         raise ValueError(f"{conf['name']}: the system's configuration "
                          f"differs from the file (stated, run): {wrong}")
@@ -138,30 +160,41 @@ def initial_state(w, shapes, rc):
     return TrainState(params, state, jnp.zeros((), jnp.int32))
 
 
-def flops_per_step(cfg, shapes, batch: int, seq: int) -> float:
-    from repro.models.xlstm import mlstm_dims
-    kinds = [s.kind for s in cfg.pattern] * cfg.n_groups
-    di, nh, p = mlstm_dims(cfg)
-    return flopsmod.train_step(
-        shapes, batch=batch, seq=seq, attn_layers=kinds.count("attn"),
-        heads=cfg.n_heads, head_dim=cfg.resolved_head_dim,
-        mlstm_layers=kinds.count("mlstm"), mlstm_heads=nh,
-        mlstm_head_dim=p)
+def flops_per_step(cell, cfg, shapes) -> float:
+    """Model FLOPs of one step (:func:`flops.train_step`); the experts a
+    token may use are counted against the published number where the
+    file's ``reduced`` cuts ``n_experts``."""
+    t = cell.traffic
+    published = cell.config.get("reduced", {}).get("n_experts", {})
+    return flopsmod.train_step(shapes, cfg, batch=t["batch"], seq=t["seq"],
+                               experts_published=published.get("published"))
 
 
-def kernel_calls(cfg, rc, batch: int, seq: int) -> dict:
-    """Operations and bytes of one call of each kernel the step runs."""
-    kinds = {s.kind for s in cfg.pattern}
+def kernel_calls(cell, cfg, rc) -> dict:
+    """Operations and bytes of one call of each kernel the step runs, by
+    tag (``flops/kernels/<tag>.py``)."""
     out = {}
-    if "attn" in kinds and rc.attention_impl == "flash":
-        out["flash_attention"] = flopsmod.flash_attention(
-            batch, seq, cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim)
-    if "mlstm" in kinds:
-        from repro.models.xlstm import mlstm_dims
-        _, nh, p = mlstm_dims(cfg)
-        out["mlstm_chunk"] = flopsmod.mlstm_chunk(batch, seq, nh, p,
-                                                  rc.mlstm_chunk)
+    for tag, mod in flopsmod.kernel_modules().items():
+        per_call = mod.call(cfg, rc, cell.traffic)
+        if per_call is not None:
+            out[tag] = per_call
     return out
+
+
+def counts(cell, cfg, rc, shapes) -> dict:
+    """The step's counts that the per-layer readers divide by, worked out
+    at set-up: a layer kind with no module fails there."""
+    return {"flops_per_step": flops_per_step(cell, cfg, shapes),
+            "kernel_calls": kernel_calls(cell, cfg, rc)}
+
+
+def trace_context(step_counts: dict, summary: dict, steps: int,
+                  peak: dict) -> dict:
+    """What ``metrics/<name>.py`` reads in a traced run: the trace's
+    reduction (``trace.reduce``), the window's steps, the step's counts
+    and the chip's peak."""
+    return {**summary, "steps": steps, **step_counts, "peak": peak,
+            "moves": TRAIN_E2E}
 
 
 # ---------------------------------------------------------------------------
@@ -216,6 +249,7 @@ class Program:
         self.rc = run_config(cell, get_config(cell.config["arch"]))
         model = Model(self.cfg)
         self.shapes = jax.eval_shape(lambda: model.init(jax.random.key(0)))
+        self.counts = counts(cell, self.cfg, self.rc, self.shapes)
         self.specs = generate.leaf_specs(self.shapes)
         self.key = jax.random.key(harness.seed_words(seed))
         rc, shapes, wkey = self.rc, self.shapes, weights_key(self.key)
@@ -401,13 +435,8 @@ def run(cell, *, seed: int, seconds: float, trace: bool, t0: float,
         summary = tracemod.reduce(tdir, tracemod.kernel_ops(
             prog.hlo))
         shutil.rmtree(tdir, ignore_errors=True)
-        ctx = {**summary, "steps": win["steps"],
-               "flops_per_step": flops_per_step(prog.cfg, prog.shapes,
-                                                t["batch"], t["seq"]),
-               "kernel_calls": kernel_calls(prog.cfg, prog.rc, t["batch"],
-                                            t["seq"]),
-               "peak": harness.peak(device["kind"]),
-               "moves": TRAIN_E2E}
+        ctx = trace_context(prog.counts, summary, win["steps"],
+                            harness.peak(device["kind"]))
     prog.free()
     ref = reference_readings(cell, seed)
     checks, correct = harness.judge(gaps(readings, ref), cell.limits)

@@ -9,9 +9,15 @@ found by the names that entry gives:
 * ``traffic/<traffic>.json``         the traffic mix, naming its driver;
 * ``drivers/<driver>.py``            builds, warms, times and checks;
 * ``limits/<workload>.json``         the limit of each number compared;
-* ``metrics/<metric>.py``            one reader per per-layer metric.
+* ``metrics/<metric>.py``            one reader per per-layer metric;
+* ``flops/kernels/<tag>.py``         one per kernel with a roofline share:
+                                     what tags its calls in the trace, and
+                                     its operations and bytes per call;
+* ``flops/layers/<kind>.py``         one per layer kind of a pattern: its
+                                     term of the model FLOPs.
 
-Adding a cell or a metric adds files; no file here changes.
+Adding a cell, a metric, a kernel or a layer kind adds files; no file here
+changes.
 """
 
 from __future__ import annotations

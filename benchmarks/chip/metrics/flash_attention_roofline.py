@@ -1,7 +1,10 @@
-"""Roofline share of the flash-attention forward kernel: the least time
-its calls could take (``flops.flash_attention``: causal pairs only, the
-larger of the FLOP and byte bounds) over their device time in the trace,
-in percent.  The kernel's backward is the jnp VJP and is not counted."""
+"""Roofline share of the flash-attention kernels: the forward's least
+time (``flops.flash_attention``: causal pairs only, the larger of the FLOP
+and byte bounds) for every call the tag covers, over their device time in
+the trace, in percent.  The tag covers a step's forward and its two
+backward kernels, ``flash_attention_dkdv`` and ``flash_attention_dq``
+(``flops/kernels/flash_attention.py``); the backward calls do more than
+the forward's products, so the share stays under 100 %."""
 
 import flops
 

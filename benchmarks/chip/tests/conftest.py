@@ -9,6 +9,7 @@ import copy
 import sys
 from pathlib import Path
 
+import jax
 import pytest
 
 HERE = Path(__file__).resolve().parents[1]
@@ -37,6 +38,28 @@ TINY_TRAIN = {"seq": 128, "batch": 2, "pool": 4, "doc_len": 32}
 TINY_TUNE = {"n_rank_samples": 40, "check_asks": 4, "check_rankings": 2,
              "bo": {"n_init": 6, "n_iter": 16, "n_candidates": 128,
                     "n_local": 32, "fit_steps": 30, "use_pallas": True}}
+
+# A configuration file for a model with both layer kinds no cell runs yet,
+# mamba and moe: jamba's smoke shape (``repro.configs``' SMOKE), 4 of a
+# published 16 experts held.
+JAMBA_SMOKE = {
+    "name": "jamba-smoke", "arch": "jamba-1.5-large-398b",
+    "source": "https://arxiv.org/abs/2408.12570",
+    "model": {"n_layers": 8, "d_model": 64, "n_heads": 4, "n_kv_heads": 2,
+              "head_dim": 16, "d_ff": 128, "vocab_size": 256,
+              "n_experts": 4, "n_experts_per_tok": 2, "moe_d_ff": 128,
+              "n_shared_experts": 0, "ssm_state_dim": 8, "ssm_expand": 2,
+              "ssm_conv_width": 4, "tie_embeddings": False,
+              "pattern": ["mamba"] * 4 + ["attn"] + ["mamba"] * 3,
+              "mlp": ["dense", "moe"] * 4,
+              "sliding_window": [None] * 8},
+    "overrides": {"n_layers": 8, "d_model": 64, "n_heads": 4,
+                  "n_kv_heads": 2, "d_ff": 128, "moe_d_ff": 128,
+                  "vocab_size": 256, "n_experts": 4, "n_experts_per_tok": 2,
+                  "ssm_state_dim": 8},
+    "reduced": {"n_experts": {"published": 16, "run": 4,
+                              "why": "a chip's share of the experts"}},
+}
 
 
 # The tuning-session cell waits, out of BENCHMARK.json, on a fault of the
@@ -84,3 +107,18 @@ def load_driver(name):
         sys.modules[mod] = harness.load_module(
             HERE / "drivers" / f"{name}.py", mod)
     return sys.modules[mod]
+
+
+def weight_flops(shapes):
+    """2 · elements of the leaves of rank ≥ 2 outside the embedding, as
+    (routed experts', the others')."""
+    routed = other = 0.0
+    for path, leaf in jax.tree_util.tree_flatten_with_path(shapes)[0]:
+        where = jax.tree_util.keystr(path)
+        if len(leaf.shape) < 2 or where.startswith("['embed']"):
+            continue
+        if "['moe']['experts']" in where:
+            routed += 2.0 * leaf.size
+        else:
+            other += 2.0 * leaf.size
+    return routed, other

@@ -4,18 +4,20 @@ the control and every fault planted under the timed path come out not
 correct.  The smoke size has limits of its own, set from its own readings
 (the cell's limits come from chip runs at the real size)."""
 
+import copy
 import json
 import math
 import shutil
 import subprocess
 import sys
 import time
+import types
 
 import jax
 import pytest
 
 import harness
-from conftest import TinyCell, load_driver
+from conftest import JAMBA_SMOKE, TinyCell, load_driver
 
 # Smoke-size limits of every number the train driver can compare, each set
 # between the program's reading at SEED on the CPU and the least of the fp8
@@ -142,3 +144,18 @@ def test_run_refuses_without_the_system(tmp_path):
         cwd=tmp_path, capture_output=True, text=True, timeout=120)
     assert out.returncode != 0
     assert out.stdout.strip() == ""
+
+
+# -- every stated width is checked ------------------------------------------
+
+@pytest.mark.parametrize("key,value", [("n_experts", 16),
+                                       ("n_routed_experts", 4),
+                                       ("mlp", ["moe"] * 8)])
+def test_stated_widths_are_checked(key, value):
+    """The file's ``model`` states what runs: a key the system runs with
+    another value, or has no setting for, fails naming it (the file as
+    it stands passes, ``test_added_files.py``)."""
+    conf = copy.deepcopy(JAMBA_SMOKE)
+    conf["model"][key] = value
+    with pytest.raises(ValueError, match=key):
+        load_driver("train").model_config(types.SimpleNamespace(config=conf))

@@ -67,6 +67,29 @@ def test_union_and_kernel_ops():
     assert tracemod.kernel_tag("fusion.47") is None
 
 
+def _custom_call(wrapper: str) -> str:
+    return ('  %custom-call.3 = bf16[8,128]{1,0} custom-call(%a), '
+            'custom_call_target="tpu_custom_call", metadata={op_name='
+            f'"jit(step_fn)/jit({wrapper})/pallas_call"}}')
+
+
+@pytest.mark.parametrize("wrapper,tag", [
+    ("flash_attention", "flash_attention"),
+    ("flash_attention_dkdv", "flash_attention"),
+    ("flash_attention_dq", "flash_attention"),
+    ("mlstm_chunk", "mlstm_chunk"),
+    ("matern52_gram", "matern52_gram"),
+    ("matern52_cross", "matern52_cross"),
+    ("grouped_matmul", None)])
+def test_kernel_ops_take_tags_from_kernel_modules(wrapper, tag):
+    """Each file under ``flops/kernels/`` tags the Mosaic calls whose line
+    holds one of its substrings; a call no module names stays untagged."""
+    want = {"custom-call.3": tag} if tag else {}
+    assert tracemod.kernel_ops(_custom_call(wrapper)) == want
+    assert tracemod.kernel_tag(f"{wrapper}.4") == (
+        wrapper if wrapper == tag else None)
+
+
 def test_self_times_leave_out_nested_operations():
     evs = [(0.0, 10.0, "while"), (1.0, 3.0, "a"), (4.0, 9.0, "b"),
            (5.0, 6.0, "c"), (11.0, 12.0, "d")]

@@ -5,6 +5,7 @@ Device operations are the events of each TPU plane's "XLA Ops" line (on a
 CPU trace, which the tests record, the host events that carry an
 ``hlo_op`` stat); each is attributed to the XLA module whose event on the
 "XLA Modules" line encloses it (on the CPU, its ``hlo_module`` stat).
+Kernel tags are the names of the files under ``flops/kernels/``.
 Host spans are the benchmark's own ``jax.profiler.TraceAnnotation``
 events, named ``bench.*``.  The window is the ``bench.window`` span.
 
@@ -29,8 +30,8 @@ from collections import defaultdict
 from pathlib import Path
 from typing import Dict, List, Optional, Tuple
 
-KERNEL_TAGS = ("flash_attention", "mlstm_chunk", "matern52_cross",
-               "matern52_gram")
+import flops
+
 _MODULE_ID = re.compile(r"\(\d+\)$")
 _OP_NAME = re.compile(r"\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=")
 _LAYOUT = re.compile(r"\{[^{}]*\}")
@@ -45,7 +46,11 @@ def op_name(text: str) -> str:
 
 def kernel_ops(hlo_text: str) -> Dict[str, str]:
     """HLO instruction name -> kernel tag, for every Mosaic custom call
-    whose metadata names one of the kernels' jitted wrappers."""
+    whose line holds one of a kernel module's ``MATCH`` substrings (its
+    metadata names the kernel's jitted wrapper).  Where several match, the
+    longest substring wins."""
+    match = [(sub, tag) for tag, mod in flops.kernel_modules().items()
+             for sub in mod.MATCH]
     out = {}
     for line in hlo_text.splitlines():
         if "tpu_custom_call" not in line:
@@ -53,10 +58,9 @@ def kernel_ops(hlo_text: str) -> Dict[str, str]:
         m = _OP_NAME.match(line)
         if not m:
             continue
-        for tag in KERNEL_TAGS:
-            if tag in line:
-                out[m.group(1)] = tag
-                break
+        hits = [(len(sub), tag) for sub, tag in match if sub in line]
+        if hits:
+            out[m.group(1)] = max(hits)[1]
     return out
 
 
@@ -67,7 +71,7 @@ def kernel_tag(name: str, ops: Optional[Dict[str, str]] = None):
     tag = (ops or {}).get(name)
     if tag is None:
         stem = name.rsplit(".", 1)[0]
-        tag = stem if stem in KERNEL_TAGS else None
+        tag = stem if stem in flops.kernel_modules() else None
     return tag
 
 
