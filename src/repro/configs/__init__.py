@@ -23,6 +23,7 @@ ARCH_IDS = [
     "qwen2_moe_a2_7b",
     "jamba_1_5_large_398b",
     "whisper_tiny",
+    "lfm2_8b_a1b",
 ]
 
 # CLI-facing ids (match the assignment spelling)
@@ -37,6 +38,7 @@ ALIASES = {
     "qwen2-moe-a2.7b": "qwen2_moe_a2_7b",
     "jamba-1.5-large-398b": "jamba_1_5_large_398b",
     "whisper-tiny": "whisper_tiny",
+    "lfm2-8b-a1b": "lfm2_8b_a1b",
 }
 
 

@@ -95,6 +95,16 @@ def matmul_flops_layer(cfg: ModelConfig, tokens: int) -> float:
     return 2.0 * per_group_active * tokens
 
 
+def routed_expert_flops(cfg: ModelConfig, tokens: int) -> float:
+    """Forward FLOPs of the routed experts a token uses, over the stack:
+    k · held / router_width experts a token and MoE layer."""
+    f = cfg.moe_d_ff if cfg.moe_d_ff is not None else cfg.d_ff
+    n_mat = 3 if cfg.act == "silu" else 2
+    moe_layers = sum(s.mlp == MLP_MOE for s in cfg.pattern) * cfg.n_groups
+    per_token = cfg.n_experts_per_tok * cfg.n_experts / cfg.router_width
+    return 2.0 * tokens * moe_layers * per_token * n_mat * cfg.d_model * f
+
+
 def _embedding_params(cfg: ModelConfig) -> int:
     emb = cfg.vocab_size * cfg.d_model
     return emb if cfg.tie_embeddings else 2 * emb
@@ -268,10 +278,16 @@ def estimate(cfg: ModelConfig, cell: ShapeCell, mesh: MeshShape,
         attn_share = attn_fwd / max(fwd_total, 1.0)
         eff = eff * (1 - attn_share) + eff_attn * attn_share
     if cfg.has_moe:
+        # ``flops`` counts the routed experts' work; each implementation
+        # executes a multiple of it: grouped (dropless) the routed rows,
+        # dropping its capacity (overcapacity pads; dropped tokens cost
+        # quality, not time), dense every held expert on every token
         cap = float(g("moe_capacity_factor", 1.25))
-        # dropping tokens hurts quality not time; overcapacity pads compute
-        flops *= (1.0 if str(g("moe_impl", "dense")) == "dense"
-                  else max(cap, 1.0))
+        executed = {"grouped": 1.0, "dropping": max(cap, 1.0),
+                    "dense": cfg.router_width / cfg.n_experts_per_tok
+                    }[str(g("moe_impl", "dense"))]
+        flops += (executed - 1.0) * (flops / fwd_total) \
+            * routed_expert_flops(cfg, tokens_global)
     compute_s = flops * prec / (mesh.chips * hw.peak_flops * max(eff, 0.05))
 
     # ---- HBM traffic ----------------------------------------------------------

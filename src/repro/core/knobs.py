@@ -112,7 +112,7 @@ def _perf_knobs(cfg: ModelConfig, cell: ShapeCell, mesh: MeshShape) -> List[Knob
         ks += [
             Knob("expert_parallel", "bool", True, module="moe"),
             Knob("moe_impl", "categorical", "dense",
-                 choices=("dense", "dropping"), module="moe"),
+                 choices=("dense", "dropping", "grouped"), module="moe"),
             Knob("moe_capacity_factor", "float", 1.25, lo=1.0, hi=2.5,
                  gated_by=("moe_impl", ("dropping",)), module="moe"),
         ]

@@ -27,7 +27,8 @@ import jax.numpy as jnp
 
 from repro.kernels.flash_attention.ref import chunked_attention
 from repro.models import common
-from repro.models.common import dense_apply, dense_axes, dense_init
+from repro.models.common import (dense_apply, dense_axes, dense_init,
+                                 norm_apply, norm_init)
 from repro.models.config import ModelConfig
 from repro.models.rotary import apply_rope
 from repro.runconfig import RunConfig
@@ -42,22 +43,38 @@ NEG_INF = -1e30
 def init(rng, cfg: ModelConfig, dtype=jnp.bfloat16):
     kq, kk, kv, ko = jax.random.split(rng, 4)
     d, qd, kvd = cfg.d_model, cfg.q_dim, cfg.kv_dim
-    return {
+    p = {
         "q": dense_init(kq, d, qd, bias=cfg.qkv_bias, dtype=dtype),
         "k": dense_init(kk, d, kvd, bias=cfg.qkv_bias, dtype=dtype),
         "v": dense_init(kv, d, kvd, bias=cfg.qkv_bias, dtype=dtype),
         "o": dense_init(ko, qd, d, dtype=dtype, scale=1.0 / math.sqrt(2 * cfg.n_layers)),
     }
+    if cfg.qk_norm:
+        p["q_norm"] = norm_init(cfg.resolved_head_dim, "rmsnorm", dtype)
+        p["k_norm"] = norm_init(cfg.resolved_head_dim, "rmsnorm", dtype)
+    return p
 
 
 def axes(cfg: ModelConfig):
     b = cfg.qkv_bias
-    return {
+    ax = {
         "q": dense_axes("qkv_in", "heads", bias=b),
         "k": dense_axes("qkv_in", "kv_heads", bias=b),
         "v": dense_axes("qkv_in", "kv_heads", bias=b),
         "o": dense_axes("heads", "o_out"),
     }
+    if cfg.qk_norm:
+        ax["q_norm"] = {"scale": ("head_dim",)}
+        ax["k_norm"] = {"scale": ("head_dim",)}
+    return ax
+
+
+def _qk_norm(params, t, which: str, cfg: ModelConfig):
+    """RMSNorm of q or k [..., heads, head_dim] over each head, where the
+    configuration asks for it (``qk_norm``)."""
+    if not cfg.qk_norm:
+        return t
+    return norm_apply(params[which], t, kind="rmsnorm", eps=cfg.norm_eps)
 
 
 # ---------------------------------------------------------------------------
@@ -136,9 +153,11 @@ def apply(params, x, positions, cfg: ModelConfig, rc: RunConfig, *,
     red = common.reduce_dtype(rc)
     q = dense_apply(params["q"], x, precision=prec,
                     preferred=red).reshape(B, S, cfg.n_heads, hd)
+    q = _qk_norm(params, q, "q_norm", cfg)
     if kv_override is None:
         k = dense_apply(params["k"], x, precision=prec,
                         preferred=red).reshape(B, S, cfg.n_kv_heads, hd)
+        k = _qk_norm(params, k, "k_norm", cfg)
         v = dense_apply(params["v"], x, precision=prec,
                         preferred=red).reshape(B, S, cfg.n_kv_heads, hd)
         if use_rope:
@@ -174,6 +193,7 @@ def project_kv(params, x, positions, cfg: ModelConfig, *, use_rope: bool = True)
     B, S, _ = x.shape
     hd = cfg.resolved_head_dim
     k = dense_apply(params["k"], x).reshape(B, S, cfg.n_kv_heads, hd)
+    k = _qk_norm(params, k, "k_norm", cfg)
     v = dense_apply(params["v"], x).reshape(B, S, cfg.n_kv_heads, hd)
     if use_rope:
         k = apply_rope(k, positions, cfg.rope_theta, cfg.mrope_sections)
@@ -205,11 +225,13 @@ def decode_apply(params, x, cache_k, cache_v, pos, cfg: ModelConfig,
     pos_vec = jnp.broadcast_to(pos.reshape(-1), (B,)) if pos.ndim <= 1 \
         else pos
     q = dense_apply(params["q"], x).reshape(B, 1, cfg.n_heads, hd)
+    q = _qk_norm(params, q, "q_norm", cfg)
     positions = pos_vec[:, None]
     if not cross:
         if use_rope:
             q = apply_rope(q, positions, cfg.rope_theta, cfg.mrope_sections)
         k_new = dense_apply(params["k"], x).reshape(B, 1, cfg.n_kv_heads, hd)
+        k_new = _qk_norm(params, k_new, "k_norm", cfg)
         v_new = dense_apply(params["v"], x).reshape(B, 1, cfg.n_kv_heads, hd)
         if use_rope:
             k_new = apply_rope(k_new, positions, cfg.rope_theta, cfg.mrope_sections)

@@ -5,6 +5,8 @@ layer stack is described by a *repeating block pattern* so that hybrid
 architectures (jamba's 1:7 attn:mamba interleave, xLSTM's 7:1 mLSTM:sLSTM)
 compile as a ``lax.scan`` over pattern *groups* rather than an unrolled
 stack — compile time scales with the pattern length, not ``n_layers``.
+A stack with no period (LFM2's 18 conv and 6 attention layers) is one
+group whose pattern is the whole stack.
 """
 
 from __future__ import annotations
@@ -18,6 +20,7 @@ ATTN = "attn"
 MAMBA = "mamba"
 MLSTM = "mlstm"
 SLSTM = "slstm"
+CONV = "conv"              # gated short convolution (LFM2)
 
 # MLP kinds.
 MLP_DENSE = "dense"
@@ -29,7 +32,7 @@ MLP_NONE = "none"
 class LayerSpec:
     """One position inside the repeating block pattern."""
 
-    kind: str = ATTN           # attn | mamba | mlstm | slstm
+    kind: str = ATTN           # attn | mamba | mlstm | slstm | conv
     mlp: str = MLP_DENSE       # dense | moe | none
     sliding_window: Optional[int] = None  # tokens; None = full attention
 
@@ -59,17 +62,27 @@ class ModelConfig:
 
     # Attention extras.
     logit_softcap: Optional[float] = None   # grok-1 attn soft-cap
+    qk_norm: bool = False                   # RMSNorm on q and k per head
     embedding_multiplier: Optional[float] = None  # grok-1 input scale
 
     # Repeating layer pattern.  n_layers must be divisible by len(pattern).
     pattern: Tuple[LayerSpec, ...] = (LayerSpec(),)
 
-    # MoE.
+    # MoE.  The layer holds ``n_experts`` experts, the block that starts
+    # at ``expert_offset`` of the ``router_experts`` the router scores
+    # (0: all of them) -- one chip's share under expert parallelism.
     n_experts: int = 0
     n_experts_per_tok: int = 0
     n_shared_experts: int = 0
     moe_d_ff: Optional[int] = None          # per-expert hidden dim
     router_aux_coef: float = 0.001
+    router_experts: int = 0
+    expert_offset: int = 0
+    router_score: str = "softmax"           # softmax | sigmoid
+    expert_bias: bool = False               # bias added for selection only
+
+    # Gated short convolution (conv layers).
+    conv_width: int = 3
 
     # SSM (mamba) dims.
     ssm_state_dim: int = 16
@@ -101,6 +114,10 @@ class ModelConfig:
             )
         if self.n_heads % self.n_kv_heads != 0:
             raise ValueError(f"{self.name}: n_kv_heads must divide n_heads")
+        if self.expert_offset + self.n_experts > self.router_width:
+            raise ValueError(f"{self.name}: held experts [{self.expert_offset}"
+                             f", {self.expert_offset + self.n_experts}) lie "
+                             f"outside the router's {self.router_width}")
 
     # ---- derived quantities -------------------------------------------------
 
@@ -126,6 +143,11 @@ class ModelConfig:
         return self.ssm_expand * self.d_model
 
     @property
+    def router_width(self) -> int:
+        """Experts the router scores; the layer holds ``n_experts`` of them."""
+        return self.router_experts or self.n_experts
+
+    @property
     def has_attention(self) -> bool:
         return any(s.kind == ATTN for s in self.pattern)
 
@@ -147,7 +169,13 @@ class ModelConfig:
         return _param_count(self, active_only=True)
 
     def scaled(self, **overrides) -> "ModelConfig":
-        """Return a reduced/altered copy (used for smoke configs)."""
+        """Return a reduced/altered copy (used for smoke configs and the
+        benchmark's cuts).  A ``pattern`` may be given as mappings of
+        :class:`LayerSpec`'s fields, as a JSON file states it."""
+        if "pattern" in overrides:
+            overrides["pattern"] = tuple(
+                s if isinstance(s, LayerSpec) else LayerSpec(**s)
+                for s in overrides["pattern"])
         return dataclasses.replace(self, **overrides)
 
 
@@ -159,14 +187,17 @@ def _mlp_params(cfg: ModelConfig, spec: LayerSpec, active_only: bool) -> int:
         f = cfg.d_ff
         n_mat = 3 if cfg.act == "silu" else 2  # SwiGLU has gate+up+down
         return n_mat * d * f
-    # MoE: routed experts + shared experts + router.
+    # MoE: routed experts + shared experts + router.  Of the held experts
+    # a token uses k · held / router_width on average.
     f = cfg.moe_d_ff if cfg.moe_d_ff is not None else cfg.d_ff
     n_mat = 3 if cfg.act == "silu" else 2
     per_expert = n_mat * d * f
-    n_routed = cfg.n_experts_per_tok if active_only else cfg.n_experts
+    n_routed = (cfg.n_experts_per_tok * cfg.n_experts / cfg.router_width
+                if active_only else cfg.n_experts)
     shared = cfg.n_shared_experts * per_expert
-    router = d * cfg.n_experts
-    return n_routed * per_expert + shared + router
+    router = d * cfg.router_width + (cfg.router_width if cfg.expert_bias
+                                     else 0)
+    return int(n_routed * per_expert) + shared + router
 
 
 def _layer_params(cfg: ModelConfig, spec: LayerSpec, active_only: bool) -> int:
@@ -174,6 +205,10 @@ def _layer_params(cfg: ModelConfig, spec: LayerSpec, active_only: bool) -> int:
     hd = cfg.resolved_head_dim
     if spec.kind == ATTN:
         core = d * cfg.q_dim + 2 * d * cfg.kv_dim + cfg.q_dim * d
+        if cfg.qk_norm:
+            core += 2 * hd
+    elif spec.kind == CONV:
+        core = d * 3 * d + cfg.conv_width * d + d * d   # in, kernel, out
     elif spec.kind == MAMBA:
         di = cfg.d_inner
         core = (

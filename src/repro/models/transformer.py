@@ -21,11 +21,11 @@ from typing import Any, Dict, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.models import attention, mlp, moe, ssm, xlstm
+from repro.models import attention, conv, mlp, moe, ssm, xlstm
 from repro.models.common import (dense_apply, norm_apply, norm_axes,
                                  norm_init, stack_axes, stack_init, trunc_normal)
-from repro.models.config import (ATTN, MAMBA, MLP_DENSE, MLP_MOE, MLP_NONE,
-    MLSTM, SLSTM, LayerSpec, ModelConfig)
+from repro.models.config import (ATTN, CONV, MAMBA, MLP_DENSE, MLP_MOE,
+    MLP_NONE, MLSTM, SLSTM, LayerSpec, ModelConfig)
 from jax.ad_checkpoint import checkpoint_name
 
 from repro.runconfig import RunConfig
@@ -50,6 +50,9 @@ def _pos_init(rng, spec: LayerSpec, cfg: ModelConfig, dtype):
     elif spec.kind == SLSTM:
         p["norm1"] = norm_init(cfg.d_model, cfg.norm, dtype)
         p["slstm"] = xlstm.slstm_init(k1, cfg, dtype)
+    elif spec.kind == CONV:
+        p["norm1"] = norm_init(cfg.d_model, cfg.norm, dtype)
+        p["conv"] = conv.init(k1, cfg, dtype)
     else:
         raise ValueError(spec.kind)
     if spec.mlp == MLP_DENSE:
@@ -75,6 +78,9 @@ def _pos_axes(spec: LayerSpec, cfg: ModelConfig):
     elif spec.kind == SLSTM:
         ax["norm1"] = norm_axes(cfg.norm)
         ax["slstm"] = xlstm.slstm_axes(cfg)
+    elif spec.kind == CONV:
+        ax["norm1"] = norm_axes(cfg.norm)
+        ax["conv"] = conv.axes(cfg)
     if spec.mlp == MLP_DENSE:
         ax["norm2"] = norm_axes(cfg.norm)
         ax["mlp"] = mlp.axes(cfg)
@@ -121,15 +127,24 @@ def axes(cfg: ModelConfig):
 _MLP_SCOPE = {MLP_DENSE: "model.mlp", MLP_MOE: "model.moe"}
 
 
+def aux_zero(cfg: ModelConfig) -> Dict[str, jnp.ndarray]:
+    """The stack's auxiliary outputs, summed over its layers: the MoE aux
+    loss (``aux``, weighted) and, where the stack has MoE layers, the
+    counters of :func:`repro.models.moe.apply`."""
+    names = ("aux", "moe_held_rows", "moe_max_group") if cfg.has_moe \
+        else ("aux",)
+    return {k: jnp.zeros((), jnp.float32) for k in names}
+
+
 def _block_forward(spec: LayerSpec, p, x, positions, cfg: ModelConfig,
                    rc: RunConfig):
-    """One block (pre-norm residual).  Returns (x, aux_loss).
+    """One block (pre-norm residual).  Returns (x, aux) (:func:`aux_zero`).
 
     The mixer and the MLP branch each run under a ``jax.named_scope``
     (``model.<kind>``, ``model.mlp`` / ``model.moe``): it names their
     operations, forward and backward, in the compiled program's metadata
     and so in a profile."""
-    aux = jnp.zeros((), jnp.float32)
+    aux = aux_zero(cfg)
     with jax.named_scope(f"model.{spec.kind}"):
         h = norm_apply(p["norm1"], x, kind=cfg.norm, eps=cfg.norm_eps)
         if spec.kind == ATTN:
@@ -141,6 +156,8 @@ def _block_forward(spec: LayerSpec, p, x, positions, cfg: ModelConfig,
             h = xlstm.mlstm_apply(p["mlstm"], h, cfg, rc)
         elif spec.kind == SLSTM:
             h = xlstm.slstm_apply(p["slstm"], h, cfg, rc)
+        elif spec.kind == CONV:
+            h = conv.apply(p["conv"], h, cfg, rc)
         x = x + h
     if spec.mlp == MLP_NONE:
         return x, aux
@@ -200,12 +217,11 @@ def backbone(params, x, positions, cfg: ModelConfig, rc: RunConfig):
             p = gather_weights_for_compute(layer_slice[p_i],
                                            pattern_axes[p_i], rc.shard)
             x, a = _block_forward(spec, p, x, positions, cfg, rc)
-            aux = aux + a
+            aux = jax.tree.map(jnp.add, aux, a)
         return (x.astype(act_dtype), aux), None
 
     body = _remat_wrap(group_body, rc)
-    (x, aux), _ = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)),
-                               params["layers"])
+    (x, aux), _ = jax.lax.scan(body, (x, aux_zero(cfg)), params["layers"])
     x = norm_apply(params["final_norm"], x, kind=cfg.norm, eps=cfg.norm_eps)
     return x, aux
 
@@ -261,7 +277,7 @@ def loss_fn(params, batch: Dict[str, jnp.ndarray], cfg: ModelConfig,
         onehot = jax.nn.one_hot(labels, cfg.vocab_size, dtype=logits.dtype)
         ll = jnp.einsum("bsv,bsv->bs", logits, onehot)
         nll = jnp.mean(logz - ll)
-    return nll + aux, {"nll": nll, "aux": aux}
+    return nll + aux["aux"], {"nll": nll, **aux}
 
 
 # ---------------------------------------------------------------------------
@@ -286,6 +302,8 @@ def _pos_state(spec: LayerSpec, batch: int, s_max: int, cfg: ModelConfig,
         return xlstm.mlstm_init_state(batch, cfg)
     if spec.kind == SLSTM:
         return xlstm.slstm_init_state(batch, cfg)
+    if spec.kind == CONV:
+        return conv.init_state(batch, cfg)
     raise ValueError(spec.kind)
 
 
@@ -298,6 +316,8 @@ def _pos_state_axes(spec: LayerSpec, cfg: ModelConfig, rc: RunConfig):
         return xlstm.mlstm_state_axes(cfg)
     if spec.kind == SLSTM:
         return xlstm.slstm_state_axes(cfg)
+    if spec.kind == CONV:
+        return conv.state_axes(cfg)
     raise ValueError(spec.kind)
 
 
@@ -340,6 +360,8 @@ def _block_decode(spec: LayerSpec, p, x, slot, pos, cfg: ModelConfig,
         h, slot = xlstm.mlstm_decode_step(p["mlstm"], h, slot, cfg, rc)
     elif spec.kind == SLSTM:
         h, slot = xlstm.slstm_decode_step(p["slstm"], h, slot, cfg, rc)
+    elif spec.kind == CONV:
+        h, slot = conv.decode_step(p["conv"], h, slot, cfg, rc)
     x = x + h
     if spec.mlp == MLP_DENSE:
         h = norm_apply(p["norm2"], x, kind=cfg.norm, eps=cfg.norm_eps)
@@ -422,6 +444,9 @@ def prefill(params, tokens, s_max: int, cfg: ModelConfig, rc: RunConfig):
             elif spec.kind == SLSTM:
                 y, slot = _slstm_prefill(p["slstm"], h, cfg, rc)
                 x = x + y
+            elif spec.kind == CONV:
+                x = x + conv.apply(p["conv"], h, cfg, rc)
+                slot = conv.final_state(p["conv"], h, cfg)
             if spec.mlp == MLP_DENSE:
                 h = norm_apply(p["norm2"], x, kind=cfg.norm, eps=cfg.norm_eps)
                 x = x + mlp.apply(p["mlp"], h, cfg, rc)

@@ -54,7 +54,9 @@ class RunConfig:
 
     # ---- MoE ----
     moe_capacity_factor: float = 1.25
-    moe_impl: str = "dense"            # dense (einsum over experts) | dropping
+    moe_impl: str = "dense"            # dense (einsum over experts) |
+                                       # dropping (capacity) | grouped
+                                       # (dropless grouped matmul)
 
     # ---- SSM / xLSTM ----
     ssm_chunk: int = 256               # chunked-scan chunk length

@@ -74,9 +74,10 @@ def test_prefill_decode(arch):
     assert int(st2.pos[0]) == int(st.pos[0]) + 1
 
 
-@pytest.mark.parametrize("arch", ["yi-6b", "xlstm-1.3b"])
+@pytest.mark.parametrize("arch", ["yi-6b", "xlstm-1.3b", "lfm2-8b-a1b"])
 def test_decode_matches_forward(arch):
-    """Teacher-forced decode == full forward logits (cache correctness)."""
+    """Teacher-forced decode == full forward logits (cache correctness; for
+    lfm2-8b-a1b the conv state and the attention cache side by side)."""
     cfg = get_smoke_config(arch)
     m = Model(cfg)
     params = m.init(jax.random.key(0))
@@ -120,6 +121,7 @@ def test_exact_assigned_dimensions():
         "qwen2_moe_a2_7b": (24, 2048, 16, 16, 151936),
         "jamba_1_5_large_398b": (72, 8192, 64, 8, 65536),
         "whisper_tiny": (4, 384, 6, 6, 51865),
+        "lfm2_8b_a1b": (24, 2048, 32, 8, 65536),
     }
     for a, (L, d, H, kv, V) in spec.items():
         c = get_config(a)
@@ -129,3 +131,11 @@ def test_exact_assigned_dimensions():
     assert get_config("qwen2_moe_a2_7b").n_experts == 60
     assert get_config("qwen2_moe_a2_7b").n_experts_per_tok == 4
     assert get_config("jamba_1_5_large_398b").n_experts == 16
+    lfm2 = get_config("lfm2_8b_a1b")
+    assert (lfm2.n_experts, lfm2.router_width, lfm2.n_experts_per_tok,
+            lfm2.moe_d_ff, lfm2.d_ff, lfm2.conv_width) == (32, 32, 4, 1792,
+                                                           7168, 3)
+    assert [s.kind for s in lfm2.pattern].count("attn") == 6
+    assert [i for i, s in enumerate(lfm2.pattern) if s.kind == "attn"] == \
+        [2, 6, 10, 14, 18, 21]
+    assert [s.mlp for s in lfm2.pattern] == ["dense"] * 2 + ["moe"] * 22

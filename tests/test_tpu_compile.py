@@ -192,3 +192,67 @@ def test_kernel_backward_keeps_its_scope(spec, kernel):
         assert sum(bwd in p for p in calls) == 2
     else:                                # a jnp backward, no kernel
         assert not calls
+
+
+# lfm2-8b-a1b's cell: batch 4 x seq 4096, top-4, so 65,536 sorted rows;
+# d_model 2048, expert width 1792, 8 experts held
+LFM2_EXPERTS = ((4 * 4096 * 4, 2048), (8, 2048, 1792), (8, 1792, 2048))
+
+
+def test_grouped_matmul_compiles_at_lfm2_widths(spec):
+    """Gate and down products and their gradients: the forward, the rows'
+    and the weights' kernels, three Mosaic calls a product."""
+    from repro.kernels.grouped_matmul.ops import grouped_matmul
+    (m, d), up, down = LFM2_EXPERTS
+
+    def loss(x, w1, w2, sizes):
+        h = grouped_matmul(x, w1, sizes, interpret=False)
+        return grouped_matmul(h, w2, sizes, interpret=False) \
+            .astype(jnp.float32).sum()
+    hlo = _compiled_text(jax.value_and_grad(loss, argnums=(0, 1, 2)),
+                         spec((m, d), jnp.bfloat16), spec(up, jnp.bfloat16),
+                         spec(down, jnp.bfloat16), spec((9,), jnp.int32))
+    calls = [line for line in hlo.splitlines() if "tpu_custom_call" in line]
+    assert len(calls) == 6
+    assert all("grouped_matmul" in line for line in calls)
+
+
+def test_lfm2_step_names_its_kernels(spec, monkeypatch):
+    """lfm2-8b-a1b's smoke train step compiled for the chip: every product
+    and kernel lies under a ``model.*`` scope, and each grouped-matmul
+    call under ``model.moe``'s ``experts``, where the benchmark's kernel
+    file (``flops/kernels/megablox_gmm.py``) tags it."""
+    import sys
+    from pathlib import Path
+    from repro.configs import get_smoke_config
+    from repro.models.model import Model
+    from repro.runconfig import runconfig_from_knobs
+    from repro.train.train_loop import init_state, make_train_step
+    sys.path.insert(0, str(Path(__file__).resolve().parents[1] /
+                           "benchmarks" / "chip"))
+    import trace as tracemod
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = get_smoke_config("lfm2-8b-a1b")
+    rc = runconfig_from_knobs({"attention_impl": "flash",
+                               "flash_block_q": 128, "flash_block_k": 128,
+                               "moe_impl": "grouped"})
+    model = Model(cfg)
+    state = jax.eval_shape(lambda: init_state(model, jax.random.key(0), rc))
+    state = jax.tree.map(lambda s: spec(s.shape, s.dtype), state)
+    batch = {k: spec((2, 256), jnp.int32) for k in ("tokens", "labels")}
+    hlo = jax.jit(make_train_step(model, rc)).lower(state, batch) \
+        .compile().as_text()
+    heavy = [line for line in hlo.splitlines()
+             if " dot(" in line or "tpu_custom_call" in line]
+    names = [re.search(r'op_name="([^"]*)"', line) for line in heavy]
+    assert heavy and all(n and "model." in n.group(1) for n in names)
+    tags = tracemod.kernel_ops(hlo)
+    gmm = {k for k, v in tags.items() if v == "megablox_gmm"}
+    # three products a layer (gate, up, down), each a forward and two
+    # backward calls, over the four MoE layers
+    assert len(gmm) == 3 * 3 * 4
+    for line in hlo.splitlines():
+        m = tracemod._OP_NAME.match(line)
+        if m and m.group(1) in gmm:
+            assert "model.moe/experts/" in line, line[:300]
+    assert set(tags.values()) == {"megablox_gmm", "flash_attention"}

@@ -20,7 +20,7 @@ import pytest
 
 from repro.compile_cache import watch_compiles
 from repro.configs import get_smoke_config
-from repro.models.config import MLP_NONE
+from repro.models.config import MLP_DENSE, MLP_MOE
 from repro.models.model import Model
 from repro.runconfig import runconfig_from_knobs
 from repro.train.train_loop import init_state, make_train_step
@@ -28,8 +28,11 @@ from repro.train.train_loop import init_state, make_train_step
 SCOPE = re.compile(r"(?:^|[/(])((?:model|train)\.[A-Za-z_]+)")
 OP_NAME = re.compile(r'op_name="([^"]*)"')
 HEAVY = (" dot(", " convolution(", " custom-call(")
-# each architecture with the attention path its benchmark cell takes
-CASES = {"yi-6b": {"attention_impl": "flash"}, "xlstm-1.3b": {}}
+# each architecture with the attention and expert paths its benchmark cell
+# takes
+CASES = {"yi-6b": {"attention_impl": "flash"}, "xlstm-1.3b": {},
+         "lfm2-8b-a1b": {"attention_impl": "flash", "moe_impl": "grouped"}}
+MLP_SCOPES = {MLP_DENSE: "model.mlp", MLP_MOE: "model.moe"}
 
 
 def _scope(op_path):
@@ -109,7 +112,7 @@ def test_scopes_in_both_directions(compiled):
     found = _all_scopes(text)
     used = {"model.embed", "model.head"}
     used |= {f"model.{s.kind}" for s in cfg.pattern}
-    used |= {"model.mlp" for s in cfg.pattern if s.mlp != MLP_NONE}
+    used |= {MLP_SCOPES[s.mlp] for s in cfg.pattern if s.mlp in MLP_SCOPES}
     for scope in sorted(used):
         assert (scope, "fwd") in found, scope
         assert (scope, "bwd") in found, scope
