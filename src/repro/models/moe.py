@@ -26,14 +26,21 @@ held set:
   expert are sorted by expert and run through the grouped-matmul Pallas
   kernel (``kernels/grouped_matmul``), so the rows computed follow the
   routing, rounded up to row tiles; each row is weighted and added back
-  to its token.  No capacity, no dropped pair.
+  to its token.  No capacity, no dropped pair.  Rows move by gathers
+  alone, forward and backward: the sorted order is a permutation of the
+  pairs and each token owns K of them, so the dispatch gathers token rows
+  by the order, the combine gathers each token's K expert rows by the
+  inverse permutation and sums them, and each one's transpose is the
+  other's gather (one custom VJP over dispatch, experts and combine).
 
-Scopes inside the caller's ``model.moe``: ``route``, ``dispatch`` (sort
-and gather), ``experts`` (the products) and ``combine``.
+Scopes inside the caller's ``model.moe``: ``route``, ``dispatch`` (the
+sort, its inverse and the token rows' gather), ``experts`` (the products)
+and ``combine`` (the weighted gather and sum).
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Dict, Tuple
 
 import jax
@@ -231,31 +238,85 @@ def _dense(params, xt, local, topv, cfg: ModelConfig, rc: RunConfig):
                           ).astype(xt.dtype)
 
 
+def _permutation(local, sizes):
+    """The T·K (token, slot) pairs sorted by held expert, stably, pairs of
+    no held expert last: ``order`` [T·K], the pair at each sorted position,
+    and its inverse ``inv`` [T, K], each pair's sorted position.
+
+    ``inv`` needs no second sort and no scatter: a pair's position is the
+    rows of the experts before its own plus the pairs of its own expert
+    before it, an exclusive cumulative sum over a one-hot [T·K, E + 1]."""
+    flat = local.reshape(-1)
+    order = jnp.argsort(flat, stable=True)
+    onehot = (flat[:, None] == jnp.arange(sizes.shape[0])).astype(jnp.int32)
+    before = jnp.cumsum(onehot, axis=0) - onehot + (jnp.cumsum(sizes) - sizes)
+    inv = jnp.sum(onehot * before, axis=1).reshape(local.shape)
+    return order, inv
+
+
+def _experts(act_name: str, xs, ep, sizes):
+    """Sorted rows xs [T·K, d] through their held experts' grouped
+    products; zero rows past the held groups."""
+    from repro.kernels.grouped_matmul.ops import grouped_matmul
+    act = activation(act_name)
+    if "gate" in ep:
+        h = act(grouped_matmul(xs, ep["gate"], sizes)) \
+            * grouped_matmul(xs, ep["up"], sizes)
+    else:
+        h = act(grouped_matmul(xs, ep["up"], sizes))
+    return grouped_matmul(h, ep["down"], sizes)
+
+
 def _grouped(params, xt, local, topv, sizes, cfg: ModelConfig):
     """Dropless: the pairs sorted by held expert through the grouped-matmul
     kernel; rows of experts held elsewhere sort last and are not
     computed."""
-    from repro.kernels.grouped_matmul.ops import grouped_matmul
-    T, d = xt.shape
-    K = cfg.n_experts_per_tok
     with jax.named_scope("dispatch"):
-        order = jnp.argsort(local.reshape(-1), stable=True)      # [T*K]
-        tok = order // K
-        xs = xt[tok]                                             # [T*K, d]
-    with jax.named_scope("experts"):
-        ep = params["experts"]
-        act = activation(cfg.act)
-        if "gate" in ep:
-            h = act(grouped_matmul(xs, ep["gate"], sizes)) \
-                * grouped_matmul(xs, ep["up"], sizes)
-        else:
-            h = act(grouped_matmul(xs, ep["up"], sizes))
-        ys = grouped_matmul(h, ep["down"], sizes)                # [T*K, d]
+        order, inv = _permutation(local, sizes)
     with jax.named_scope("combine"):
-        w = topv.reshape(-1)[order][:, None]
-        y = jnp.zeros((T, d), jnp.float32).at[tok].add(
-            ys.astype(jnp.float32) * w)
-    return y.astype(xt.dtype)
+        # a held-elsewhere pair weighs exactly 0, whatever its row holds
+        w = jnp.where(local < cfg.n_experts, topv, 0.0)
+    return _dropless(cfg.act, xt, w, params["experts"], sizes, order, inv)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _dropless(act_name, xt, w, ep, sizes, order, inv):
+    """Dispatch, experts and combine: y[t] = Σ_k w[t, k] · ys[inv[t, k]]
+    for ys the experts' rows of xt[order // K].  Every move of rows, in
+    both directions, is a gather by ``order`` or by ``inv``."""
+    return _dropless_fwd(act_name, xt, w, ep, sizes, order, inv)[0]
+
+
+def _dropless_fwd(act_name, xt, w, ep, sizes, order, inv):
+    K = inv.shape[1]
+    with jax.named_scope("dispatch"):
+        xs = xt[order // K]                                      # [T*K, d]
+    with jax.named_scope("experts"):
+        ys, experts_vjp = jax.vjp(
+            lambda xs, ep: _experts(act_name, xs, ep, sizes), xs, ep)
+    with jax.named_scope("combine"):
+        y = jnp.sum(w[..., None] * ys[inv].astype(jnp.float32), axis=1)
+    return y.astype(xt.dtype), (w, order, inv, ys, experts_vjp)
+
+
+def _dropless_bwd(act_name, res, dy):
+    w, order, inv, ys, experts_vjp = res
+    K = inv.shape[1]
+    with jax.named_scope("combine"):
+        # dy's row for each sorted pair: it gives the rows' gradient and,
+        # against the rows themselves, the weights'
+        dyo = dy.astype(jnp.float32)[order // K]                 # [T*K, d]
+        dys = (w.reshape(-1)[order][:, None] * dyo).astype(ys.dtype)
+        dw = jnp.sum(dyo * ys.astype(jnp.float32), axis=1)[inv]  # [T, K]
+        dw = dw.astype(w.dtype)
+    with jax.named_scope("experts"):
+        dxs, dep = experts_vjp(dys)
+    with jax.named_scope("dispatch"):
+        dxt = jnp.sum(dxs[inv].astype(jnp.float32), axis=1)
+    return dxt.astype(dxs.dtype), dw, dep, None, None, None
+
+
+_dropless.defvjp(_dropless_fwd, _dropless_bwd)
 
 
 def _capacity_dispatch(params, xt, local, topv, cfg: ModelConfig,

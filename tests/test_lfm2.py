@@ -2,7 +2,8 @@
 float32 reference the chip benchmark checks it with
 (``benchmarks/chip/configs/lfm2-8b-a1b.reference.py``), the expert layer's
 shares against the uncut layer, the grouped-matmul kernel (interpret mode)
-against its jnp oracle, and the gated short convolution against a direct
+against its jnp oracle, the grouped layer's gathers against the scatter-add
+form they replace, and the gated short convolution against a direct
 formula.
 
 Everything is float32 here, parameters and activations, so the tolerances
@@ -11,6 +12,7 @@ mechanism left out or computed differently fails them by orders of
 magnitude."""
 
 import importlib.util
+import re
 from pathlib import Path
 
 import jax
@@ -184,6 +186,110 @@ def test_grouped_matmul_matches_ref(case, dtype):
             assert _rel(g.astype(jnp.float32), e) < tol
         else:
             assert not np.any(np.asarray(g, np.float32))
+
+
+def _scatter_form(params, xt, local, topv, sizes, cfg):
+    """The grouped layer's dispatch and combine as plain autodiff sees
+    them: a gather of the token rows by the sorted order, and a weighted
+    scatter-add of the experts' rows back onto their tokens."""
+    K = cfg.n_experts_per_tok
+    order = jnp.argsort(local.reshape(-1), stable=True)
+    tok = order // K
+    ys = moe._experts(cfg.act, xt[tok], params["experts"], sizes)
+    w = topv.reshape(-1)[order][:, None]
+    y = jnp.zeros(xt.shape, jnp.float32).at[tok].add(
+        ys.astype(jnp.float32) * w)
+    return y.astype(xt.dtype)
+
+
+def _routed(held, rest, d_model=64, K=4, seed=0):
+    """A held-share layer (one expert per held group) and a routing whose
+    (token, slot) pairs fill the groups as ``GMM_CASES`` gives them:
+    (cfg, params, local [T, K], topv [T, K], sizes [E + 1])."""
+    sizes = np.asarray(held + [rest])
+    E, N = len(held), int(sizes.sum())
+    cfg = get_smoke_config("lfm2-8b-a1b").scaled(
+        d_model=d_model, n_experts=E, n_experts_per_tok=K)
+    rng = np.random.default_rng(seed)
+    local = rng.permutation(np.repeat(np.arange(E + 1), sizes))
+    kp, kv = jax.random.split(jax.random.key(seed))
+    params = {"experts": moe.init(kp, cfg, jnp.float32)["experts"]}
+    topv = jax.random.uniform(kv, (N // K, K), minval=0.1)
+    return (cfg, params, jnp.asarray(local.reshape(N // K, K), jnp.int32),
+            topv, jnp.asarray(sizes, jnp.int32))
+
+
+@pytest.mark.parametrize("case", sorted(GMM_CASES))
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_grouped_gathers_match_scatter_form(case, dtype):
+    """The grouped layer's permutation gathers against autodiff of the
+    scatter-add form: the value, and the gradients of the token rows, the
+    routing weights and every expert weight."""
+    cfg, params, local, topv, sizes = _routed(*GMM_CASES[case])
+    params = jax.tree.map(lambda t: t.astype(dtype), params)
+    xt = jax.random.normal(jax.random.key(11),
+                           (local.shape[0], cfg.d_model)).astype(dtype)
+    dy = jax.random.normal(jax.random.key(12), xt.shape).astype(dtype)
+
+    def run(form):
+        return jax.vjp(lambda x, v, p: form(p, x, local, v, sizes, cfg),
+                       xt, topv, params)
+    with jax.default_matmul_precision("highest"):
+        (y, got_vjp), (want, want_vjp) = run(moe._grouped), \
+            run(_scatter_form)
+        pairs = [(y, want)] + list(zip(jax.tree.leaves(got_vjp(dy)),
+                                       jax.tree.leaves(want_vjp(dy))))
+    assert y.dtype == want.dtype == dtype
+    assert len(pairs) == 3 + len(params["experts"])
+    tol = 1e-5 if dtype == jnp.float32 else 2e-2
+    for g, e in pairs:
+        assert g.dtype == e.dtype
+        g, e = np.asarray(g, np.float32), np.asarray(e, np.float32)
+        if np.any(e):
+            assert _rel(g, e) < tol
+        else:
+            assert not np.any(g)
+
+
+def test_grouped_gradients_numerically():
+    """The custom VJP against finite differences, in float32, at small
+    shapes."""
+    from jax.test_util import check_grads
+    cfg, params, local, topv, sizes = _routed([5, 0, 9, 2], 16, d_model=16)
+    order, inv = moe._permutation(local, sizes)
+    xt = jax.random.normal(jax.random.key(13), (local.shape[0], 16))
+    w = jnp.where(local < cfg.n_experts, topv, 0.0)
+    check_grads(lambda x, v, ep: moe._dropless(cfg.act, x, v, ep, sizes,
+                                               order, inv),
+                (xt, w, params["experts"]), order=1, modes=["rev"])
+
+
+def test_grouped_step_has_no_row_scatter():
+    """No scatter moves rows in the loss's value and gradient of a one-layer
+    lfm2 smoke model under ``grouped`` (StableHLO, lowered on the CPU).
+    The scatters left are the embedding's gradient [vocab, d], the router's
+    top-k pick [T, router_width] and the grouped-matmul kernel's tile
+    metadata (1-D, shorter than T); a dispatch or combine by scatter adds
+    one into [T, d] or over the T·K pairs."""
+    cfg = get_smoke_config("lfm2-8b-a1b").scaled(
+        n_layers=1, pattern=[{"kind": "conv", "mlp": "moe"}])
+    rc = RunConfig(moe_impl="grouped", **F32)
+    params = Model(cfg).init(jax.random.key(0), jnp.float32)
+    tokens, labels = _tokens(cfg, b=2, s=24)
+
+    def loss(p):
+        return transformer.loss_fn(p, {"tokens": tokens, "labels": labels},
+                                   cfg, rc)[0]
+    text = jax.jit(jax.value_and_grad(loss)).lower(params).as_text()
+    shapes = {tuple(int(n) for n in m.group(1).split("x")[:-1])
+              for m in re.finditer(
+                  r'stablehlo\.scatter"\(.*?\}\) : \(.*?\) -> '
+                  r'tensor<([^>]*)>', text, re.S)}
+    T = tokens.size
+    allowed = {(cfg.vocab_size, cfg.d_model), (T, cfg.router_width)}
+    assert (cfg.vocab_size, cfg.d_model) in shapes    # the search finds
+    assert all(s in allowed or (len(s) == 1 and s[0] < T)
+               for s in shapes), shapes
 
 
 def test_conv_matches_direct_formula():
